@@ -1479,6 +1479,8 @@ if __name__ == "__main__":
                     choices=("poisson", "bursty", "diurnal"))
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     main(cluster_only=args.cluster_only, adaptation_only=args.adaptation_only,
          preemption_only=args.preemption_only, prefix_only=args.prefix_only,
          chunked_only=args.chunked_only, refine_only=args.refine_only,

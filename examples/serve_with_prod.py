@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import use_compile_cache
 from repro.common.config import PredictorConfig, TrainConfig
 from repro.configs import get_config
 from repro.core import bins as B
@@ -66,6 +67,7 @@ def main():
     ap.add_argument("--n-serve", type=int, default=96)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     # -- 1. train the served LM ---------------------------------------------
     cfg = get_config("tiny-lm").with_overrides(dtype="float32")

@@ -25,11 +25,20 @@ NEG_INF = -1e30
 
 def attn_spec(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     hd = cfg.head_dim
+    # explicit 1/sqrt(fan_in): the default rule reads shape[-2], which for
+    # these 3-D projections is a head count, not the contracted width (at
+    # d=3584 it made random-weight attention logits ~300 wide, i.e. argmax)
+    s_in = 1.0 / math.sqrt(cfg.d_model)
+    s_out = 1.0 / math.sqrt(cfg.n_heads * hd)
     s = {
-        "wq": ParamSpec((cfg.d_model, cfg.n_heads, hd), ("embed", "heads", "head_dim")),
-        "wk": ParamSpec((cfg.d_model, cfg.n_kv_heads, hd), ("embed", "kv_heads", "head_dim")),
-        "wv": ParamSpec((cfg.d_model, cfg.n_kv_heads, hd), ("embed", "kv_heads", "head_dim")),
-        "wo": ParamSpec((cfg.n_heads, hd, cfg.d_model), ("heads", "head_dim", "embed")),
+        "wq": ParamSpec((cfg.d_model, cfg.n_heads, hd), ("embed", "heads", "head_dim"),
+                        scale=s_in),
+        "wk": ParamSpec((cfg.d_model, cfg.n_kv_heads, hd), ("embed", "kv_heads", "head_dim"),
+                        scale=s_in),
+        "wv": ParamSpec((cfg.d_model, cfg.n_kv_heads, hd), ("embed", "kv_heads", "head_dim"),
+                        scale=s_in),
+        "wo": ParamSpec((cfg.n_heads, hd, cfg.d_model), ("heads", "head_dim", "embed"),
+                        scale=s_out),
     }
     if cfg.qk_norm:
         s["q_norm"] = ParamSpec((hd,), ("head_dim",), init="zeros")

@@ -11,6 +11,7 @@ From the single spec we derive:
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -37,21 +38,26 @@ def _is_spec(x) -> bool:
 def init_tree(key: jax.Array, spec_tree: Any, dtype) -> Any:
     leaves, treedef = jax.tree_util.tree_flatten(spec_tree, is_leaf=_is_spec)
     keys = jax.random.split(key, len(leaves))
-
-    def one(k, s: ParamSpec):
-        if s.init == "zeros":
-            return jnp.zeros(s.shape, dtype)
-        if s.init == "ones":
-            return jnp.ones(s.shape, dtype)
-        if s.init == "embed":
-            return (jax.random.normal(k, s.shape) * (s.scale or 0.02)).astype(dtype)
-        fan_in = s.shape[-2] if len(s.shape) >= 2 else s.shape[-1]
-        scale = s.scale if s.scale is not None else 1.0 / np.sqrt(max(fan_in, 1))
-        return (jax.random.normal(k, s.shape) * scale).astype(dtype)
-
+    dtype = jnp.dtype(dtype)
     return jax.tree_util.tree_unflatten(
-        treedef, [one(k, s) for k, s in zip(keys, leaves)]
+        treedef, [_init_leaf(k, s, dtype) for k, s in zip(keys, leaves)]
     )
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _init_leaf(k: jax.Array, s: ParamSpec, dtype) -> jax.Array:
+    """One leaf, drawn and cast inside one program: the float32 draw of a
+    stacked (layers, d, d_ff) leaf is fused into the cast and never lands in
+    device memory whole (at full width it alone would be several GB)."""
+    if s.init == "zeros":
+        return jnp.zeros(s.shape, dtype)
+    if s.init == "ones":
+        return jnp.ones(s.shape, dtype)
+    if s.init == "embed":
+        return (jax.random.normal(k, s.shape) * (s.scale or 0.02)).astype(dtype)
+    fan_in = s.shape[-2] if len(s.shape) >= 2 else s.shape[-1]
+    scale = s.scale if s.scale is not None else 1.0 / np.sqrt(max(fan_in, 1))
+    return (jax.random.normal(k, s.shape) * scale).astype(dtype)
 
 
 def shape_tree(spec_tree: Any, dtype) -> Any:
@@ -133,9 +139,15 @@ def embed_spec(vocab: int, d_model: int) -> ParamSpec:
 
 
 def unembed(x: jax.Array, w_embed: jax.Array, w_head: Optional[jax.Array]) -> jax.Array:
-    """Project hidden states to vocab logits (fp32 for loss stability)."""
+    """Project hidden states to float32 vocab logits (for loss stability).
+
+    The (d, V) matrix is read in its own dtype and accumulated in float32:
+    casting it first would write a float32 copy of the whole vocabulary
+    projection on every call."""
     w = w_embed.T if w_head is None else w_head
-    return (x.astype(jnp.float32)) @ (w.astype(jnp.float32))
+    dt = jnp.promote_types(x.dtype, w.dtype)
+    return jnp.matmul(x.astype(dt), w.astype(dt),
+                      preferred_element_type=jnp.float32)
 
 
 def sinusoid_positions(n_pos: int, d_model: int) -> np.ndarray:

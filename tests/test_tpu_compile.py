@@ -1,0 +1,141 @@
+"""Compile the served path for a described TPU v5e chip, with no chip attached.
+
+The TPU compiler is installed with JAX, and it compiles for a topology that is
+described rather than present. These tests catch what interpret mode cannot:
+a kernel Mosaic refuses to lower, more VMEM than a kernel may use, a step
+program that does not fit the chip's 16 GB. Nothing runs, so they say
+nothing about results or times.
+
+The topology is described inside a module fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file. Keep these tests in this one file, so the worker that runs them is
+the only one that loads it.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+HBM_BYTES = 16 * 1024**3   # one v5e chip
+
+# the shapes chip_smoke.py serves: Qwen2.5-7B widths, 8 prompts right-padded
+# to 1024, 128 new tokens
+B, SP, MAX_NEW = 8, 1024, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Shape-only arguments on one described chip, compiled with the
+    persistent compilation cache off: an executable compiled for a described
+    chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def shape(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
+
+    yield shape
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _on_chip(tree, shape):
+    return jax.tree_util.tree_map(lambda s: shape(s.shape, s.dtype), tree)
+
+
+def _fits(compiled):
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+    assert used < HBM_BYTES, used
+    return used
+
+
+@pytest.mark.parametrize("block_b", [8, 128])
+def test_prod_head_kernel_compiles_at_served_width(one_chip, block_b):
+    from repro.kernels.prod_head import prod_head_pallas
+
+    f32 = jnp.float32
+    d, hidden, K, Q = 3584, 512, 64, 3
+    fn = jax.jit(lambda phi, w1, b1, w2, b2, e, q: prod_head_pallas(
+        phi, w1, b1, w2, b2, e, qs=q, block_b=block_b))
+    compiled = fn.lower(one_chip((block_b, d), f32), one_chip((d, hidden), f32),
+                        one_chip((hidden,), f32), one_chip((hidden, K), f32),
+                        one_chip((K,), f32), one_chip((K + 1,), f32),
+                        one_chip((Q,), f32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_attention_kernel_compiles(one_chip):
+    from repro.kernels.decode_attention import decode_attention_pallas
+
+    H, KV, hd, Sc = 28, 4, 128, SP + MAX_NEW
+    compiled = jax.jit(decode_attention_pallas).lower(
+        one_chip((B, H, hd)), one_chip((B, Sc, KV, hd)),
+        one_chip((B, Sc, KV, hd)), one_chip((B,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_kernel_compiles(one_chip):
+    from repro.kernels.flash_attention import flash_attention_pallas
+
+    H, KV, hd = 28, 4, 128
+    compiled = jax.jit(flash_attention_pallas).lower(
+        one_chip((B, SP, H, hd)), one_chip((B, SP, KV, hd)),
+        one_chip((B, SP, KV, hd))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def served(one_chip):
+    """RealEngine's jitted steps over Qwen2.5-7B widths at 2 layers (the
+    layers are scanned, so depth changes no kernel, only the loop count)."""
+    from repro.configs import get_config
+    from repro.models.model_zoo import build_model
+    from repro.serving.engine import RealEngine
+
+    model = build_model(get_config("qwen2.5-7b").with_overrides(n_layers=2))
+    params = _on_chip(model.param_shapes(), one_chip)
+    return model, params, RealEngine(model, params, max_new=MAX_NEW)
+
+
+def test_served_prefill_step_compiles(served, one_chip):
+    model, params, eng = served
+    batch = {"tokens": one_chip((B, SP), jnp.int32),
+             "attn_valid": one_chip((B, SP), jnp.bool_)}
+    compiled = eng._jit_prefill.lower(params, batch,
+                                      one_chip((B,), jnp.int32)).compile()
+    _fits(compiled)
+    logits, phi, _ = compiled.out_info
+    assert logits.shape == (B, model.cfg.vocab_size)
+    assert logits.dtype == jnp.float32
+    assert phi.shape == (B, model.cfg.d_model)
+
+
+def test_served_decode_step_compiles(served, one_chip):
+    model, params, eng = served
+    cache = _on_chip(model.cache_shapes(B, SP + MAX_NEW), one_chip)
+    ids = one_chip((B,), jnp.int32)
+    compiled = eng._jit_decode.lower(
+        params, {"tokens": ids, "pos": ids, "lengths": ids}, cache).compile()
+    _fits(compiled)
+    logits = compiled.out_info[0]
+    assert logits.shape == (B, model.cfg.vocab_size)
