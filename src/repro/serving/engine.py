@@ -1305,13 +1305,28 @@ class SimEngine:
 # ---------------------------------------------------------------------------
 
 
+# Every span the program writes into a profiler trace starts with this prefix;
+# the benchmark's trace reader finds the program's spans by it (a contract).
+SPAN_PREFIX = "repro."
+_ENGINE_SPAN = SPAN_PREFIX + "engine."
+
+
 class RealEngine:
     """Batched sampling engine over a real model: prefill once, decode until
     EOS, harvest last-token hidden states for the ProD predictor.
 
     Every :meth:`generate` call appends its host-clock timings, each ending
     in ``block_until_ready``, to ``timings``: ``prefill_s``, ``decode_s``
-    (the whole decode loop, host sampling included) and ``decode_steps``."""
+    (the whole decode loop, host sampling included) and ``decode_steps``,
+    with the counter ``host_syncs`` (device→host reads in the decode loop).
+
+    Under a profiler, each call also writes the host spans
+    ``repro.engine.generate`` (args ``call``, ``B``, ``Sp``, ``max_new``)
+    around ``.prefill``, ``.grow_cache`` and ``.decode``; the decode loop
+    holds one ``.step`` (``step_num``) per iteration, each around
+    ``.sample``, ``.sync`` (one per device→host read) and ``.dispatch``.
+    The decode program is ``jit_decode_step`` and the prefill
+    ``jit_prefill``: both names are contracts of the trace's readers."""
 
     def __init__(self, model, params, rt=None, temperature: float = 0.8,
                  max_new: int = 256, eos_id: int = 2):
@@ -1326,6 +1341,7 @@ class RealEngine:
         self.max_new = max_new
         self.eos = eos_id
         self.timings: List[Dict[str, float]] = []
+        self._calls = 0
 
         def prefill(p, b, lengths):
             # no (B, S, V) logits: only the last valid token is unembedded
@@ -1333,10 +1349,11 @@ class RealEngine:
             phi = last_token_hidden(hidden, lengths)
             return unembed(phi, p["embed"], p.get("head")), phi, cache
 
+        def decode_step(p, b, c):
+            return model.decode_step(p, b, c, self.rt)
+
         self._jit_prefill = jax.jit(prefill)
-        self._jit_decode = jax.jit(
-            lambda p, b, c: model.decode_step(p, b, c, self.rt)
-        )
+        self._jit_decode = jax.jit(decode_step)
 
     def generate(self, prompts: np.ndarray, prompt_lens: np.ndarray, key,  # reprolint: disable=wall-clock -- timings are reported, no decision reads them
                  collect_hidden: bool = True, collect_per_step: bool = False,
@@ -1354,58 +1371,90 @@ class RealEngine:
 
         import jax
         import jax.numpy as jnp
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        def span(name, **args):
+            # about a microsecond when no profiler is running
+            return TraceAnnotation(_ENGINE_SPAN + name, **args)
 
         B, Sp = prompts.shape
         cfg = self.model.cfg
-        valid = np.arange(Sp)[None, :] < prompt_lens[:, None]
-        batch = {"tokens": jnp.asarray(prompts),
-                 "attn_valid": jnp.asarray(valid)}
-        lengths = jnp.asarray(prompt_lens, jnp.int32)
-        t0 = time.perf_counter()
-        cur_logits, phi, cache = jax.block_until_ready(
-            self._jit_prefill(self.params, batch, lengths))
-        prefill_s = time.perf_counter() - t0
+        self._calls += 1
+        host_syncs = 0
 
-        # move prefill cache into a decode cache with room for max_new tokens
-        cache = self._grow_cache(cache, Sp + self.max_new, Sp)
-        finished = jnp.zeros(B, bool)
-        out_tokens = np.zeros((B, self.max_new), np.int32)
-        gen_len = np.zeros(B, np.int64)
-        step_hidden = (np.zeros((B, self.max_new, cfg.d_model), np.float32)
-                       if collect_per_step else None)
-        step_valid = (np.zeros((B, self.max_new), bool)
-                      if collect_per_step else None)
-        step_logits = {}
-        steps = 0
-        t1 = time.perf_counter()
-        for step in range(self.max_new):
-            key, sub = jax.random.split(key)
-            nxt = jax.random.categorical(sub, cur_logits / self.temp, axis=-1)
-            nxt = jnp.where(finished, self.eos, nxt).astype(jnp.int32)
-            out_tokens[:, step] = np.asarray(nxt)
-            newly = (~finished) & (nxt == self.eos)
-            finished = finished | (nxt == self.eos)
-            gen_len = np.where(np.asarray(newly), step + 1, gen_len)
-            if bool(finished.all()):
-                break
-            dbatch = {"tokens": nxt, "pos": lengths, "lengths": lengths + 1}
-            cur_logits, hid_t, cache = self._jit_decode(self.params, dbatch, cache)
-            steps += 1
-            if step in logit_steps:
-                step_logits[step] = np.asarray(cur_logits, np.float32)
-            if collect_per_step:
-                step_hidden[:, step] = np.asarray(hid_t, np.float32)
-                step_valid[:, step] = ~np.asarray(finished)
-            lengths = lengths + jnp.where(finished, 0, 1)
-        jax.block_until_ready(cur_logits)
-        self.timings.append({"prefill_s": prefill_s,
-                             "decode_s": time.perf_counter() - t1,
-                             "decode_steps": steps})
-        gen_len = np.where(gen_len == 0, self.max_new, gen_len)
-        return {"lengths": gen_len,
-                "phi": np.asarray(phi) if collect_hidden else None,
-                "tokens": out_tokens, "step_hidden": step_hidden,
-                "step_valid": step_valid, "step_logits": step_logits}
+        def sync(x, to_host=np.asarray):
+            # every device→host read of the decode loop: spanned and counted
+            nonlocal host_syncs
+            host_syncs += 1
+            with span("sync"):
+                return to_host(x)
+
+        def all_true(x):
+            return bool(x.all())
+
+        def as_f32(x):
+            return np.asarray(x, np.float32)
+
+        with span("generate", call=self._calls, B=B, Sp=Sp, max_new=self.max_new):
+            valid = np.arange(Sp)[None, :] < prompt_lens[:, None]
+            batch = {"tokens": jnp.asarray(prompts),
+                     "attn_valid": jnp.asarray(valid)}
+            lengths = jnp.asarray(prompt_lens, jnp.int32)
+            with span("prefill"):
+                t0 = time.perf_counter()
+                cur_logits, phi, cache = jax.block_until_ready(
+                    self._jit_prefill(self.params, batch, lengths))
+                prefill_s = time.perf_counter() - t0
+
+            # move prefill cache into a decode cache with room for max_new tokens
+            with span("grow_cache"):
+                cache = self._grow_cache(cache, Sp + self.max_new, Sp)
+            finished = jnp.zeros(B, bool)
+            out_tokens = np.zeros((B, self.max_new), np.int32)
+            gen_len = np.zeros(B, np.int64)
+            step_hidden = (np.zeros((B, self.max_new, cfg.d_model), np.float32)
+                           if collect_per_step else None)
+            step_valid = (np.zeros((B, self.max_new), bool)
+                          if collect_per_step else None)
+            step_logits = {}
+            steps = 0
+            with span("decode"):
+                t1 = time.perf_counter()
+                for step in range(self.max_new):
+                    with StepTraceAnnotation(_ENGINE_SPAN + "step", step_num=step):
+                        with span("sample"):
+                            key, sub = jax.random.split(key)
+                            nxt = jax.random.categorical(sub, cur_logits / self.temp,
+                                                         axis=-1)
+                            nxt = jnp.where(finished, self.eos, nxt).astype(jnp.int32)
+                        out_tokens[:, step] = sync(nxt)
+                        with span("sample"):
+                            newly = (~finished) & (nxt == self.eos)
+                            finished = finished | (nxt == self.eos)
+                        gen_len = np.where(sync(newly), step + 1, gen_len)
+                        if sync(finished, all_true):
+                            break
+                        with span("dispatch"):
+                            dbatch = {"tokens": nxt, "pos": lengths,
+                                      "lengths": lengths + 1}
+                            cur_logits, hid_t, cache = self._jit_decode(
+                                self.params, dbatch, cache)
+                            lengths = lengths + jnp.where(finished, 0, 1)
+                        steps += 1
+                        if step in logit_steps:
+                            step_logits[step] = sync(cur_logits, as_f32)
+                        if collect_per_step:
+                            step_hidden[:, step] = sync(hid_t, as_f32)
+                            step_valid[:, step] = ~sync(finished)
+                jax.block_until_ready(cur_logits)
+                decode_s = time.perf_counter() - t1
+            self.timings.append({"prefill_s": prefill_s, "decode_s": decode_s,
+                                 "decode_steps": steps, "host_syncs": host_syncs})
+            gen_len = np.where(gen_len == 0, self.max_new, gen_len)
+            return {"lengths": gen_len,
+                    "phi": np.asarray(phi) if collect_hidden else None,
+                    "tokens": out_tokens, "step_hidden": step_hidden,
+                    "step_valid": step_valid, "step_logits": step_logits}
 
     def _grow_cache(self, cache, new_len: int, old_len: int):
         import jax.numpy as jnp
