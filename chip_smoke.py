@@ -207,7 +207,7 @@ def run(seed: int = 0, arch: str = ARCH, n_layers: int = N_LAYERS,
     print(f"prefill: {prefill_s:.6f} s (B={n_prompts}, S={prompts.shape[1]}, "
           f"median of {len(steady)} warm calls, to block_until_ready)", flush=True)
     print(f"decode: {1e3 * dec_s / dec_steps:.4f} ms per step "
-          f"(B={n_prompts}, one token per sequence, host sampling included; "
+          f"(B={n_prompts}, one token per sequence, the draw included; "
           f"{dec_steps} warm steps)", flush=True)
 
     worst, n = decode_vs_prefill(eng, prompts, plens, seed)

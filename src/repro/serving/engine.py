@@ -1311,22 +1311,67 @@ SPAN_PREFIX = "repro."
 _ENGINE_SPAN = SPAN_PREFIX + "engine."
 
 
+def _draw_then_decode(model_step, eos: int):
+    """The decode loop's one program per step, named ``decode_step`` as the
+    bare ``model_step`` is (``jit_decode_step`` in traces): draw token
+    ``step`` from the previous step's logits, record it, then decode it.
+
+    ``state`` holds the loop's device state: ``tokens`` (B, max_new) int32,
+    ``finished`` (B,), ``gen_len`` (B,) int32 (0 until the row emits EOS)
+    and ``lengths`` (B,). ``temp`` and ``step`` are arguments, so a new
+    temperature or step traces nothing. Returns (state, key, logits, hidden,
+    cache, every row finished)."""
+    import jax
+    import jax.numpy as jnp
+
+    def decode_step(p, logits, key, temp, state, step, cache):
+        key, sub = jax.random.split(key)
+        nxt = jax.random.categorical(sub, logits / temp, axis=-1)
+        finished = state["finished"]
+        nxt = jnp.where(finished, eos, nxt).astype(jnp.int32)
+        tokens = jax.lax.dynamic_update_slice_in_dim(state["tokens"], nxt[:, None],
+                                                     step, axis=1)
+        newly = (~finished) & (nxt == eos)
+        finished = finished | (nxt == eos)
+        gen_len = jnp.where(newly, step + 1, state["gen_len"])
+        lengths = state["lengths"]
+        logits, hidden, cache = model_step(
+            p, {"tokens": nxt, "pos": lengths, "lengths": lengths + 1}, cache)
+        state = {"tokens": tokens, "finished": finished, "gen_len": gen_len,
+                 "lengths": lengths + jnp.where(finished, 0, 1)}
+        return state, key, logits, hidden, cache, finished.all()
+
+    return decode_step
+
+
 class RealEngine:
     """Batched sampling engine over a real model: prefill once, decode until
     EOS, harvest last-token hidden states for the ProD predictor.
 
+    The decode loop runs one program per step, ``jit_decode_step``, which
+    draws the step's token on the device and then decodes it. The host never
+    waits on the device inside the loop except to read whether every row had
+    finished one step earlier, while the next step is already queued; so a
+    loop that stops on EOS has dispatched one step after the stop, whose
+    results are dropped. Tokens and lengths are read once, after the loop.
+
     Every :meth:`generate` call appends its host-clock timings, each ending
     in ``block_until_ready``, to ``timings``: ``prefill_s``, ``decode_s``
-    (the whole decode loop, host sampling included) and ``decode_steps``,
-    with the counter ``host_syncs`` (device→host reads in the decode loop).
+    (the whole decode loop, to its tokens on the host) and ``decode_steps``
+    (the model steps the tokens needed: the step that finishes the last row
+    is not one), with the counters ``host_syncs`` (device→host reads of the
+    decode loop: one per step but the first, two after it, and those
+    ``collect_per_step`` and ``logit_steps`` ask for) and ``overrun_steps``
+    (steps dispatched after every row had finished, 0 or 1).
 
     Under a profiler, each call also writes the host spans
     ``repro.engine.generate`` (args ``call``, ``B``, ``Sp``, ``max_new``)
     around ``.prefill``, ``.grow_cache`` and ``.decode``; the decode loop
     holds one ``.step`` (``step_num``) per iteration, each around
-    ``.sample``, ``.sync`` (one per device→host read) and ``.dispatch``.
-    The decode program is ``jit_decode_step`` and the prefill
-    ``jit_prefill``: both names are contracts of the trace's readers."""
+    ``.dispatch`` and ``.sync`` (one per device→host read), and ends in the
+    final reads' ``.sync``. The decode program is ``jit_decode_step`` and the
+    prefill ``jit_prefill``: both names are contracts of the trace's
+    readers."""
 
     def __init__(self, model, params, rt=None, temperature: float = 0.8,
                  max_new: int = 256, eos_id: int = 2):
@@ -1354,6 +1399,7 @@ class RealEngine:
 
         self._jit_prefill = jax.jit(prefill)
         self._jit_decode = jax.jit(decode_step)
+        self._jit_step = jax.jit(_draw_then_decode(decode_step, eos_id))
 
     def generate(self, prompts: np.ndarray, prompt_lens: np.ndarray, key,  # reprolint: disable=wall-clock -- timings are reported, no decision reads them
                  collect_hidden: bool = True, collect_per_step: bool = False,
@@ -1366,7 +1412,8 @@ class RealEngine:
 
         ``logit_steps`` also returns ``step_logits`` {step: (B, V) float32}:
         the logits the decode step at index ``step`` computed, i.e. after
-        consuming ``tokens[:, step]`` at position ``prompt_lens + step``."""
+        consuming ``tokens[:, step]`` at position ``prompt_lens + step``.
+        The per-step reads these two ask for wait on each step."""
         import time
 
         import jax
@@ -1389,9 +1436,6 @@ class RealEngine:
             with span("sync"):
                 return to_host(x)
 
-        def all_true(x):
-            return bool(x.all())
-
         def as_f32(x):
             return np.asarray(x, np.float32)
 
@@ -1409,47 +1453,49 @@ class RealEngine:
             # move prefill cache into a decode cache with room for max_new tokens
             with span("grow_cache"):
                 cache = self._grow_cache(cache, Sp + self.max_new, Sp)
-            finished = jnp.zeros(B, bool)
-            out_tokens = np.zeros((B, self.max_new), np.int32)
-            gen_len = np.zeros(B, np.int64)
+            state = {"tokens": jnp.zeros((B, self.max_new), jnp.int32),
+                     "finished": jnp.zeros(B, bool),
+                     "gen_len": jnp.zeros(B, jnp.int32), "lengths": lengths}
+            temp = jnp.float32(self.temp)
             step_hidden = (np.zeros((B, self.max_new, cfg.d_model), np.float32)
                            if collect_per_step else None)
             step_valid = (np.zeros((B, self.max_new), bool)
                           if collect_per_step else None)
             step_logits = {}
-            steps = 0
+            overrun = 0
+            done = None       # every row finished after the previous step
             with span("decode"):
                 t1 = time.perf_counter()
                 for step in range(self.max_new):
                     with StepTraceAnnotation(_ENGINE_SPAN + "step", step_num=step):
-                        with span("sample"):
-                            key, sub = jax.random.split(key)
-                            nxt = jax.random.categorical(sub, cur_logits / self.temp,
-                                                         axis=-1)
-                            nxt = jnp.where(finished, self.eos, nxt).astype(jnp.int32)
-                        out_tokens[:, step] = sync(nxt)
-                        with span("sample"):
-                            newly = (~finished) & (nxt == self.eos)
-                            finished = finished | (nxt == self.eos)
-                        gen_len = np.where(sync(newly), step + 1, gen_len)
-                        if sync(finished, all_true):
-                            break
                         with span("dispatch"):
-                            dbatch = {"tokens": nxt, "pos": lengths,
-                                      "lengths": lengths + 1}
-                            cur_logits, hid_t, cache = self._jit_decode(
-                                self.params, dbatch, cache)
-                            lengths = lengths + jnp.where(finished, 0, 1)
-                        steps += 1
+                            new_state, key, cur_logits, hid_t, cache, new_done = (
+                                self._jit_step(self.params, cur_logits, key, temp,
+                                               state, step, cache))
+                        # waits for the previous step only: this one is queued
+                        if done is not None and sync(done, bool):
+                            overrun = 1
+                            break
+                        state, done = new_state, new_done
                         if step in logit_steps:
                             step_logits[step] = sync(cur_logits, as_f32)
                         if collect_per_step:
                             step_hidden[:, step] = sync(hid_t, as_f32)
-                            step_valid[:, step] = ~sync(finished)
+                            step_valid[:, step] = ~sync(state["finished"])
                 jax.block_until_ready(cur_logits)
+                out_tokens = sync(state["tokens"])
+                gen_len = sync(state["gen_len"]).astype(np.int64)
                 decode_s = time.perf_counter() - t1
+            # the token that finishes the last row is never decoded
+            steps = (int(gen_len.max()) - 1 if (gen_len > 0).all()
+                     else self.max_new)
+            step_logits = {s: v for s, v in step_logits.items() if s < steps}
+            if collect_per_step:
+                step_hidden[:, steps:] = 0
+                step_valid[:, steps:] = False
             self.timings.append({"prefill_s": prefill_s, "decode_s": decode_s,
-                                 "decode_steps": steps, "host_syncs": host_syncs})
+                                 "decode_steps": steps, "host_syncs": host_syncs,
+                                 "overrun_steps": overrun})
             gen_len = np.where(gen_len == 0, self.max_new, gen_len)
             return {"lengths": gen_len,
                     "phi": np.asarray(phi) if collect_hidden else None,

@@ -139,3 +139,22 @@ def test_served_decode_step_compiles(served, one_chip):
     _fits(compiled)
     logits = compiled.out_info[0]
     assert logits.shape == (B, model.cfg.vocab_size)
+
+
+def test_served_draw_and_decode_step_compiles(served, one_chip):
+    """The program the decode loop runs: the token's draw, then the step."""
+    model, params, eng = served
+    cache = _on_chip(model.cache_shapes(B, SP + MAX_NEW), one_chip)
+    state = {"tokens": one_chip((B, MAX_NEW), jnp.int32),
+             "finished": one_chip((B,), jnp.bool_),
+             "gen_len": one_chip((B,), jnp.int32),
+             "lengths": one_chip((B,), jnp.int32)}
+    key = _on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+    compiled = eng._jit_step.lower(
+        params, one_chip((B, model.cfg.vocab_size), jnp.float32), key,
+        one_chip((), jnp.float32), state, one_chip((), jnp.int32), cache).compile()
+    _fits(compiled)
+    new_state, _, logits, _, _, done = compiled.out_info
+    assert new_state["tokens"].shape == (B, MAX_NEW)
+    assert logits.shape == (B, model.cfg.vocab_size)
+    assert done.shape == () and done.dtype == jnp.bool_
