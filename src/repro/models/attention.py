@@ -180,50 +180,53 @@ def blocked_attention(
     return out[:, :Sq0].astype(v.dtype)
 
 
+def kv_lanes(t: jax.Array) -> jax.Array:
+    """(..., KV, hd) -> (..., KV·hd): decode caches keep heads and head width
+    merged in their minor dimension, so that narrow heads (hd 64) stay
+    lane-dense on the TPU instead of being laid out with the sequence minor."""
+    return t.reshape(*t.shape[:-2], t.shape[-2] * t.shape[-1])
+
+
 def decode_attention(
     q: jax.Array,                # (B, 1, H, hd)
-    k_cache: jax.Array,          # (B, Sc, KV, hd)
-    v_cache: jax.Array,          # (B, Sc, KV, hd)
+    k_cache: jax.Array,          # (B, Sc, KV·hd): heads and head width merged
+    v_cache: jax.Array,          # (B, Sc, KV·hd)
     valid: jax.Array,            # (B, Sc) bool — which cache slots participate
 ) -> jax.Array:
-    """Single-token attention against a KV cache. Returns (B, 1, H, hd)."""
+    """Single-token attention against a lane-dense KV cache. Returns (B, 1, H, hd).
+
+    Both products contract the cache's merged minor dimension whole, as
+    matrix products: the scores against a block-diagonal query (each query
+    head nonzero only on its K/V head's lanes), the values for every head
+    before each head keeps its own K/V head's lanes. The zeros add exactly
+    nothing; the cache is read once, in its own layout."""
     B, _, H, hd = q.shape
-    KV = k_cache.shape[2]
+    C = k_cache.shape[-1]
+    KV = C // hd
     G = H // KV
     scale = 1.0 / math.sqrt(hd)
     with jax.named_scope("fusedkernel_decode_attention"):
-        qg = q.reshape(B, KV, G, hd)
+        own = (jnp.arange(C)[:, None] // hd) == (jnp.arange(H)[None, :] // G)  # (C, H)
+        q_lanes = jnp.tile(q.reshape(B, H, hd), (1, 1, KV))                   # (B, H, C)
+        q_bd = jnp.where(own.T[None], q_lanes, 0).astype(k_cache.dtype)
         s = jnp.einsum(
-            "bkgh,bskh->bkgs", qg, k_cache, preferred_element_type=jnp.float32
+            "bhc,bsc->bhs", q_bd, k_cache, preferred_element_type=jnp.float32
         ) * scale
-        s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+        s = jnp.where(valid[:, None, :], s, NEG_INF)
         m = jnp.max(s, axis=-1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=-1, keepdims=True)
-        o = jnp.einsum(
-            "bkgs,bskh->bkgh", (p / jnp.maximum(l, 1e-30)).astype(v_cache.dtype),
+        o_all = jnp.einsum(
+            "bhs,bsc->bhc", (p / jnp.maximum(l, 1e-30)).astype(v_cache.dtype),
             v_cache, preferred_element_type=jnp.float32,
-        )
+        )                                                                      # (B, H, C)
+        o = jnp.sum(jnp.where(own.T[None], o_all, 0).reshape(B, H, KV, hd), axis=2)
     return o.reshape(B, 1, H, hd).astype(v_cache.dtype)
 
 
 # ---------------------------------------------------------------------------
 # KV caches (static-shape, TPU-idiomatic)
 # ---------------------------------------------------------------------------
-
-
-def cache_write_full(k_cache, v_cache, k_new, v_new, pos):
-    """Write one token at absolute position ``pos`` (scalar int32)."""
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k_new.astype(k_cache.dtype), (0, pos, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v_new.astype(v_cache.dtype), (0, pos, 0, 0))
-    return k_cache, v_cache
-
-
-def cache_write_ring(k_cache, v_cache, k_new, v_new, pos, window: int):
-    slot = jnp.mod(pos, window)
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k_new.astype(k_cache.dtype), (0, slot, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v_new.astype(v_cache.dtype), (0, slot, 0, 0))
-    return k_cache, v_cache
 
 
 def full_cache_valid(lengths: jax.Array, S: int) -> jax.Array:

@@ -185,12 +185,12 @@ def decoder_decode_step(
             kc = kc.at[bidx, pos].set(k[:, 0].astype(kc.dtype))
             vc = vc.at[bidx, pos].set(v[:, 0].astype(vc.dtype))
             valid = attn.full_cache_valid(lengths, Sc)
-        o = attn.decode_attention(q, kc, vc, valid)
+        o = attn.decode_attention(q, attn.kv_lanes(kc), attn.kv_lanes(vc), valid)
         x = x + attn.out_project(lp["self_attn"], o)
         h = _ln(lp["ln2"], x, cfg.norm_eps)
         qc = jnp.einsum("bsd,dhk->bshk", h, lp["cross_attn"]["wq"])
         all_valid = jnp.ones((B, ck.shape[1]), bool)
-        oc = attn.decode_attention(qc, ck, cv, all_valid)
+        oc = attn.decode_attention(qc, attn.kv_lanes(ck), attn.kv_lanes(cv), all_valid)
         x = x + attn.out_project(lp["cross_attn"], oc)
         h = _ln(lp["ln3"], x, cfg.norm_eps)
         x = x + mlp_apply(lp["mlp"], h, "gelu")

@@ -223,22 +223,23 @@ def _apply_layer_seq(lp, shared, x, ctx: Ctx, kind: str, angles, attn_valid):
     cache = None
     if ctx.mode == "prefill":
         W = _attn_kind_window(cfg, kind)
+        k, v = attn.kv_lanes(k), attn.kv_lanes(v)
         cache = _ring_from_prefill(k, v, W) if W else {"k": k, "v": v}
     return x, cache, aux
 
 
 def _ring_from_prefill(k, v, W: int):
-    B, S, KV, hd = k.shape
+    B, S, C = k.shape
     if S <= W:
         pad = W - S
-        kr = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vr = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        kr = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
+        vr = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
         # token t sits at slot t % W == t for t < S — already aligned
         return {"k": kr, "v": vr}
     t = jnp.arange(S - W, S, dtype=jnp.int32)
     slots = jnp.mod(t, W)
-    kr = jnp.zeros((B, W, KV, hd), k.dtype).at[:, slots].set(k[:, S - W :])
-    vr = jnp.zeros((B, W, KV, hd), v.dtype).at[:, slots].set(v[:, S - W :])
+    kr = jnp.zeros((B, W, C), k.dtype).at[:, slots].set(k[:, S - W :])
+    vr = jnp.zeros((B, W, C), v.dtype).at[:, slots].set(v[:, S - W :])
     return {"k": kr, "v": vr}
 
 
@@ -325,16 +326,17 @@ def cache_spec(cfg: ModelConfig, batch: int, cache_len: int,
     """ParamSpec pytree (shapes + logical axes) for the decode cache.
 
     Windowed layers allocate a ring of ``min(window, cache_len)``; full layers
-    allocate ``cache_len``. SSM layers carry (h, conv) state. With
-    ``kv_quant`` the K/V tensors are int8 with per-(token, kv-head) fp32
-    scales (beyond-paper serving optimization — halves decode cache reads).
+    allocate ``cache_len``. K/V are (layers, batch, seq, KV·hd): heads and
+    head width merged, lane-dense for every head width. SSM layers carry
+    (h, conv) state. With ``kv_quant`` the K/V tensors are int8 with
+    per-(token, kv-head) fp32 scales (beyond-paper serving optimization —
+    halves decode cache reads).
     """
     plan = layer_plan(cfg)
     H, P, N, d_conv = (0, 0, 0, 0)
     if cfg.family in ("ssm", "hybrid"):
         H, P, N, d_conv = ssm_mod.ssm_dims(cfg)
-    kv_ax = ("layers", "batch", "cache_seq", "cache_kv_heads", "head_dim")
-    sc_ax = ("layers", "batch", "cache_seq", "cache_kv_heads")
+    kv_ax = ("layers", "batch", "cache_seq", "cache_kv_heads")
     segs = []
     for seg in plan:
         entries = {}
@@ -350,7 +352,7 @@ def cache_spec(cfg: ModelConfig, batch: int, cache_len: int,
             else:
                 W = _attn_kind_window(cfg, kind)
                 Sc = min(W, cache_len) if W else cache_len
-                kv_shape = (n, batch, Sc, cfg.n_kv_heads, cfg.head_dim)
+                kv_shape = (n, batch, Sc, cfg.n_kv_heads * cfg.head_dim)
                 e = {
                     "k": ParamSpec(kv_shape, kv_ax,
                                    init="int8" if kv_quant else "normal"),
@@ -358,9 +360,9 @@ def cache_spec(cfg: ModelConfig, batch: int, cache_len: int,
                                    init="int8" if kv_quant else "normal"),
                 }
                 if kv_quant:
-                    e["k_s"] = ParamSpec((n, batch, Sc, cfg.n_kv_heads), sc_ax,
+                    e["k_s"] = ParamSpec((n, batch, Sc, cfg.n_kv_heads), kv_ax,
                                          init="f32")
-                    e["v_s"] = ParamSpec((n, batch, Sc, cfg.n_kv_heads), sc_ax,
+                    e["v_s"] = ParamSpec((n, batch, Sc, cfg.n_kv_heads), kv_ax,
                                          init="f32")
                 entries[f"layer_{j}"] = e
         segs.append(entries)
@@ -390,14 +392,19 @@ def _quantize_kv(t):
     return jnp.clip(q, -127, 127).astype(jnp.int8), scale
 
 
-def _decode_attn_layer(lp, x, entry, ctx: Ctx, kind: str, pos, lengths, angles):
-    """One cached attention layer for a single new token."""
+def _decode_attn_layer(lp, x, entry, i, ctx: Ctx, kind: str, pos, lengths, angles):
+    """One cached attention layer for a single new token.
+
+    ``entry`` is the segment's stacked cache for this layer position,
+    (n_blocks, B, Sc, ·); the token's K/V (and int8 scales) are written into
+    it in place at ``[i, row, slot]``, then block ``i``'s slice is read for
+    attention. Returns (x, the updated stacked entry)."""
     cfg = ctx.cfg
     B = x.shape[0]
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     q, k, v = attn.qkv_project(lp["attn"], h, cfg, angles)  # (B,1,·,hd)
     W = _attn_kind_window(cfg, kind)
-    Sc = entry["k"].shape[1]
+    Sc = entry["k"].shape[2]
     ring = bool(W) and Sc == W  # ring iff allocated exactly the window
     bidx = jnp.arange(B)
     quant = "k_s" in entry
@@ -407,12 +414,14 @@ def _decode_attn_layer(lp, x, entry, ctx: Ctx, kind: str, pos, lengths, angles):
     else:
         k_new, v_new = k[:, 0], v[:, 0]
     slot = jnp.mod(pos, Sc) if ring else pos
-    kc = entry["k"].at[bidx, slot].set(k_new.astype(entry["k"].dtype))
-    vc = entry["v"].at[bidx, slot].set(v_new.astype(entry["v"].dtype))
-    new_entry = {"k": kc, "v": vc}
+    new_entry = {
+        "k": entry["k"].at[i, bidx, slot].set(attn.kv_lanes(k_new).astype(entry["k"].dtype)),
+        "v": entry["v"].at[i, bidx, slot].set(attn.kv_lanes(v_new).astype(entry["v"].dtype)),
+    }
     if quant:
-        new_entry["k_s"] = entry["k_s"].at[bidx, slot].set(ks_new)
-        new_entry["v_s"] = entry["v_s"].at[bidx, slot].set(vs_new)
+        new_entry["k_s"] = entry["k_s"].at[i, bidx, slot].set(ks_new)
+        new_entry["v_s"] = entry["v_s"].at[i, bidx, slot].set(vs_new)
+    kc, vc = new_entry["k"][i], new_entry["v"][i]
     if ring:
         valid = attn.ring_cache_valid(lengths, Sc)
     else:
@@ -424,11 +433,15 @@ def _decode_attn_layer(lp, x, entry, ctx: Ctx, kind: str, pos, lengths, angles):
         # dequant INSIDE the kernel scope: on TPU the Pallas decode kernel
         # reads int8 + scales from HBM and dequantizes in VMEM
         with jax.named_scope("fusedkernel_decode_attention_dequant"):
-            kd = (kc.astype(jnp.float32) * new_entry["k_s"][..., None]).astype(cfg.dtype)
-            vd = (vc.astype(jnp.float32) * new_entry["v_s"][..., None]).astype(cfg.dtype)
-        o = attn.decode_attention(q, kd, vd, valid)
-    else:
-        o = attn.decode_attention(q, kc, vc, valid)
+            hd = cfg.head_dim
+
+            def dequant(c, s):
+                c = c.reshape(*s.shape, hd).astype(jnp.float32) * s[..., None]
+                return attn.kv_lanes(c).astype(cfg.dtype)
+
+            kc = dequant(kc, new_entry["k_s"][i])
+            vc = dequant(vc, new_entry["v_s"][i])
+    o = attn.decode_attention(q, kc, vc, valid)
     x = x + attn.out_project(lp["attn"], o)
     return x, new_entry
 
@@ -443,7 +456,11 @@ def decode_step(
     ctx: Optional[Ctx] = None,
     embeds: Optional[jax.Array] = None,
 ):
-    """One decode step. Returns (logits (B, V), hidden (B, d), new_cache, aux)."""
+    """One decode step. Returns (logits (B, V), hidden (B, d), new_cache, aux).
+
+    Each segment's stacked cache is carried through the layer scan as loop
+    state and updated in place, so a caller that donates ``cache`` holds one
+    cache, not an input and an output."""
     ctx = ctx or Ctx(cfg=cfg, mode="decode")
     B = tokens.shape[0]
     if embeds is None:
@@ -464,29 +481,30 @@ def decode_step(
     for seg, seg_params, seg_cache in zip(plan, params["segments"], cache):
 
         def block_fn(carry, xs, _kinds=seg.kinds):
-            x, aux = carry
-            block_p, block_c = xs
-            new_entries = {}
+            x, aux, c = carry
+            block_p, i = xs
+            c = dict(c)
             for j, kind in enumerate(_kinds):
-                lp = block_p[f"layer_{j}"]
-                entry = block_c[f"layer_{j}"]
+                name = f"layer_{j}"
+                lp = block_p[name]
                 if kind == "ssm":
                     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-                    y, st = ssm_mod.ssm_decode_step(lp["ssm"], h, entry, cfg)
+                    st = jax.tree_util.tree_map(lambda a: a[i], c[name])
+                    y, st = ssm_mod.ssm_decode_step(lp["ssm"], h, st, cfg)
                     x = x + y
-                    new_entries[f"layer_{j}"] = st
+                    c[name] = jax.tree_util.tree_map(lambda a, s: a.at[i].set(s),
+                                                     c[name], st)
                 else:
                     p = shared if kind == "shared_attn" else lp
-                    x, ce = _decode_attn_layer(p, x, entry, ctx, kind, pos, lengths,
-                                               angles(kind))
+                    x, c[name] = _decode_attn_layer(p, x, c[name], i, ctx, kind, pos,
+                                                    lengths, angles(kind))
                     x, a = _apply_ffn(p, x, ctx, "moe" if kind == "moe" else "mlp")
                     aux = aux + a
-                    new_entries[f"layer_{j}"] = ce
-            return (x, aux), new_entries
+            return (x, aux, c), None
 
-        (x, aux_total), seg_new = jax.lax.scan(
-            block_fn, (x, aux_total), (seg_params, seg_cache)
-        )
+        (x, aux_total, seg_new), _ = jax.lax.scan(
+            block_fn, (x, aux_total, seg_cache),
+            (seg_params, jnp.arange(seg.n_blocks, dtype=jnp.int32)))
         new_cache.append(seg_new)
 
     hidden = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)

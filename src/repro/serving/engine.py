@@ -1309,6 +1309,8 @@ class SimEngine:
 # the benchmark's trace reader finds the program's spans by it (a contract).
 SPAN_PREFIX = "repro."
 _ENGINE_SPAN = SPAN_PREFIX + "engine."
+# the cache leaves with a sequence axis (axis 2), which _grow_cache pads
+_KV_LEAVES = ("k", "v", "k_s", "v_s")
 
 
 def _draw_then_decode(model_step, eos: int):
@@ -1361,15 +1363,17 @@ class RealEngine:
     (the model steps the tokens needed: the step that finishes the last row
     is not one), with the counters ``host_syncs`` (device→host reads of the
     decode loop: one per step but the first, two after it, and those
-    ``collect_per_step`` and ``logit_steps`` ask for) and ``overrun_steps``
-    (steps dispatched after every row had finished, 0 or 1).
+    ``collect_per_step`` and ``logit_steps`` ask for), ``overrun_steps``
+    (steps dispatched after every row had finished, 0 or 1) and
+    ``cache_bytes`` (the bytes of the cache the decode loop holds). The
+    cache is donated to each step, which writes its token in place.
 
     Under a profiler, each call also writes the host spans
     ``repro.engine.generate`` (args ``call``, ``B``, ``Sp``, ``max_new``)
-    around ``.prefill``, ``.grow_cache`` and ``.decode``; the decode loop
-    holds one ``.step`` (``step_num``) per iteration, each around
-    ``.dispatch`` and ``.sync`` (one per device→host read), and ends in the
-    final reads' ``.sync``. The decode program is ``jit_decode_step`` and the
+    around ``.prefill``, ``.grow_cache`` (arg ``cache_bytes``) and
+    ``.decode``; the decode loop holds one ``.step`` (``step_num``) per
+    iteration, each around ``.dispatch`` and ``.sync`` (one per device→host
+    read), and ends in the final reads' ``.sync``. The decode program is ``jit_decode_step`` and the
     prefill ``jit_prefill``: both names are contracts of the trace's
     readers."""
 
@@ -1381,25 +1385,31 @@ class RealEngine:
 
         self.model = model
         self.params = params
-        self.rt = rt or Runtime.local()
+        self.rt = rt = rt or Runtime.local()
         self.temp = temperature
         self.max_new = max_new
         self.eos = eos_id
         self.timings: List[Dict[str, float]] = []
         self._calls = 0
 
+        # the jitted programs close over model and rt, never over self: an
+        # engine that is dropped frees its parameters at once, not at the
+        # garbage collector's next pass
         def prefill(p, b, lengths):
             # no (B, S, V) logits: only the last valid token is unembedded
-            _, hidden, cache, _ = model.prefill(p, b, self.rt, logits_mode="none")
+            _, hidden, cache, _ = model.prefill(p, b, rt, logits_mode="none")
             phi = last_token_hidden(hidden, lengths)
             return unembed(phi, p["embed"], p.get("head")), phi, cache
 
         def decode_step(p, b, c):
-            return model.decode_step(p, b, c, self.rt)
+            return model.decode_step(p, b, c, rt)
 
         self._jit_prefill = jax.jit(prefill)
         self._jit_decode = jax.jit(decode_step)
-        self._jit_step = jax.jit(_draw_then_decode(decode_step, eos_id))
+        # the cache is donated: the step writes its token into it in place,
+        # so the loop holds one cache, not a queued step's input and output
+        self._jit_step = jax.jit(_draw_then_decode(decode_step, eos_id),
+                                 donate_argnums=6)
 
     def generate(self, prompts: np.ndarray, prompt_lens: np.ndarray, key,  # reprolint: disable=wall-clock -- timings are reported, no decision reads them
                  collect_hidden: bool = True, collect_per_step: bool = False,
@@ -1451,8 +1461,12 @@ class RealEngine:
                 prefill_s = time.perf_counter() - t0
 
             # move prefill cache into a decode cache with room for max_new tokens
-            with span("grow_cache"):
-                cache = self._grow_cache(cache, Sp + self.max_new, Sp)
+            new_len = Sp + self.max_new
+            grown = jax.eval_shape(lambda c: self._grow_cache(c, new_len, Sp), cache)
+            cache_bytes = sum(x.size * x.dtype.itemsize
+                              for x in jax.tree_util.tree_leaves(grown))
+            with span("grow_cache", cache_bytes=cache_bytes):
+                cache = self._grow_cache(cache, new_len, Sp)
             state = {"tokens": jnp.zeros((B, self.max_new), jnp.int32),
                      "finished": jnp.zeros(B, bool),
                      "gen_len": jnp.zeros(B, jnp.int32), "lengths": lengths}
@@ -1472,7 +1486,9 @@ class RealEngine:
                             new_state, key, cur_logits, hid_t, cache, new_done = (
                                 self._jit_step(self.params, cur_logits, key, temp,
                                                state, step, cache))
-                        # waits for the previous step only: this one is queued
+                        # waits for the previous step only: this one is queued.
+                        # After a break nothing may read the cache handed to
+                        # the dropped step: it was donated
                         if done is not None and sync(done, bool):
                             overrun = 1
                             break
@@ -1495,7 +1511,7 @@ class RealEngine:
                 step_valid[:, steps:] = False
             self.timings.append({"prefill_s": prefill_s, "decode_s": decode_s,
                                  "decode_steps": steps, "host_syncs": host_syncs,
-                                 "overrun_steps": overrun})
+                                 "overrun_steps": overrun, "cache_bytes": cache_bytes})
             gen_len = np.where(gen_len == 0, self.max_new, gen_len)
             return {"lengths": gen_len,
                     "phi": np.asarray(phi) if collect_hidden else None,
@@ -1506,16 +1522,17 @@ class RealEngine:
         import jax.numpy as jnp
         import jax.tree_util as jtu
 
-        def grow(x):
-            # attention caches: (..., S, KV, hd) with S == old_len (ring caches
-            # are allocated at their window and left alone)
-            if x.ndim >= 4 and x.shape[-3] == old_len:
+        def grow(path, x):
+            # attention K/V (and int8 scales): (layers, B, S, ·) with S ==
+            # old_len; ring caches are allocated at their window and left
+            # alone, SSM state has no sequence axis
+            if getattr(path[-1], "key", None) in _KV_LEAVES and x.shape[2] == old_len:
                 pad = [(0, 0)] * x.ndim
-                pad[-3] = (0, new_len - old_len)
+                pad[2] = (0, new_len - old_len)
                 return jnp.pad(x, pad)
             return x
 
-        return jtu.tree_map(grow, cache)
+        return jtu.tree_map_with_path(grow, cache)
 
     def repeated_sampling(self, prompts: np.ndarray, prompt_lens: np.ndarray,
                           r: int, seed: int = 0):

@@ -115,9 +115,10 @@ def test_decode_matches_forward(arch):
     _, _, cache, _ = jp(params, pre)
 
     def grow(x):
-        if x.ndim >= 3 and x.shape[-3] == S:
+        # caches keep the sequence on axis 2: (layers, B, S, ·)
+        if x.ndim >= 3 and x.shape[2] == S:
             pad = [(0, 0)] * x.ndim
-            pad[-3] = (0, 8)
+            pad[2] = (0, 8)
             return jnp.pad(x, pad)
         return x
 

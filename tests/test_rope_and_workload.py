@@ -103,7 +103,7 @@ class TestWorkloadRules:
         specs = m.cache_specs(batch=1, cache_len=524_288)
         sizes = sorted({s.shape[2] for seg in specs
                         for e in seg.values() for s in e.values()
-                        if len(s.shape) == 5})
+                        if len(s.shape) == 4})  # (layers, B, S, KV·hd)
         assert sizes == [1024, 524_288]  # local rings + global full
         # ring layers outnumber global layers 5:1
         n_ring = sum(s.shape[0] for seg in specs for e in seg.values()

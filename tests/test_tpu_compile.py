@@ -158,3 +158,37 @@ def test_served_draw_and_decode_step_compiles(served, one_chip):
     assert new_state["tokens"].shape == (B, MAX_NEW)
     assert logits.shape == (B, model.cfg.vocab_size)
     assert done.shape == () and done.dtype == jnp.bool_
+
+
+# the benchmark's cells: (layers, rows, prompts padded to, new tokens)
+CELLS = {"minicpm-2b": (40, 8, 1024, 256), "qwen2.5-7b": (14, 32, 512, 256)}
+
+
+@pytest.mark.parametrize("arch", sorted(CELLS))
+def test_decode_loop_step_holds_one_cache_updated_in_place(one_chip, arch):
+    """The decode loop's program at a cell's full shapes: its cache argument
+    is donated and written in place (aliased whole, no cache-sized
+    temporary), and weights, cache and temporaries leave the chip room for
+    the prefill's cache beside them."""
+    from repro.configs import get_config
+    from repro.models.model_zoo import build_model
+    from repro.serving.engine import RealEngine
+
+    L, b, sp, new = CELLS[arch]
+    model = build_model(get_config(arch).with_overrides(n_layers=L))
+    params = _on_chip(model.param_shapes(), one_chip)
+    eng = RealEngine(model, params, max_new=new)
+    cache = _on_chip(model.cache_shapes(b, sp + new), one_chip)
+    state = {"tokens": one_chip((b, new), jnp.int32),
+             "finished": one_chip((b,), jnp.bool_),
+             "gen_len": one_chip((b,), jnp.int32),
+             "lengths": one_chip((b,), jnp.int32)}
+    key = _on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+    m = eng._jit_step.lower(
+        params, one_chip((b, model.cfg.vocab_size), jnp.float32), key,
+        one_chip((), jnp.float32), state, one_chip((), jnp.int32),
+        cache).compile().memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(cache))
+    assert m.alias_size_in_bytes >= cache_bytes
+    assert m.temp_size_in_bytes < 0.25e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes <= 0.6 * HBM_BYTES
