@@ -27,25 +27,25 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _resolve(impl: str) -> str:
+def resolve(impl: str) -> str:
     if impl == "auto":
         return "pallas" if _on_tpu() else "xla"
     return impl
 
 
-def flash_attention(q, k, v, *, causal=True, window=0, block_q=128, block_kv=128,
-                    impl="auto"):
-    impl = _resolve(impl)
+def flash_attention(q, k, v, lengths=None, *, causal=True, window=0,
+                    block_q=None, block_kv=None, impl="auto"):
+    impl = resolve(impl)
     if impl == "xla":
-        return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
+        return ref.flash_attention_ref(q, k, v, lengths, causal=causal, window=window)
     return flash_attention_pallas(
-        q, k, v, causal=causal, window=window, block_q=block_q, block_kv=block_kv,
-        interpret=(impl == "interpret"),
+        q, k, v, lengths, causal=causal, window=window, block_q=block_q,
+        block_kv=block_kv, interpret=(impl == "interpret"),
     )
 
 
 def decode_attention(q, k, v, lengths, *, block_kv=256, impl="auto"):
-    impl = _resolve(impl)
+    impl = resolve(impl)
     if impl == "xla":
         return ref.decode_attention_ref(q, k, v, lengths)
     return decode_attention_pallas(
@@ -54,7 +54,7 @@ def decode_attention(q, k, v, lengths, *, block_kv=256, impl="auto"):
 
 
 def ssd_scan(x, dt, a, Bm, Cm, *, chunk=128, impl="auto"):
-    impl = _resolve(impl)
+    impl = resolve(impl)
     if impl == "xla":
         return ref.ssd_scan_ref(x, dt, a, Bm, Cm)
     S = x.shape[1]
@@ -75,7 +75,7 @@ def ssd_scan(x, dt, a, Bm, Cm, *, chunk=128, impl="auto"):
 def prod_head(phi, w1, b1, w2, b2, edges, *, qs=None, block_b=128, impl="auto"):
     """Fused head. ``qs=None`` returns (probs, median); ``qs`` an array of
     CDF levels returns (probs, quants (B, Q)) — all levels in one call."""
-    impl = _resolve(impl)
+    impl = resolve(impl)
     if impl == "xla":
         return ref.prod_head_ref(phi, w1, b1, w2, b2, edges, qs=qs)
     return prod_head_pallas(phi, w1, b1, w2, b2, edges, qs=qs, block_b=block_b,

@@ -17,11 +17,14 @@ def flash_attention_ref(
     q: jax.Array,            # (B, Sq, H, hd)
     k: jax.Array,            # (B, Skv, KV, hd)
     v: jax.Array,            # (B, Skv, KV, hd)
+    lengths: Optional[jax.Array] = None,   # (B,) real tokens per row
     *,
     causal: bool = True,
     window: int = 0,
     q_offset: int = 0,
 ) -> jax.Array:
+    """With ``lengths``, keys at or past a row's length are masked and its
+    queries there give zeros, as the kernel does."""
     B, Sq, H, hd = q.shape
     _, Skv, KV, _ = k.shape
     G = H // KV
@@ -30,15 +33,20 @@ def flash_attention_ref(
                    k.astype(jnp.float32)) / math.sqrt(hd)
     qpos = q_offset + jnp.arange(Sq)
     kpos = jnp.arange(Skv)
-    mask = jnp.ones((Sq, Skv), bool)
+    mask = jnp.ones((1, Sq, Skv), bool)
     if causal:
         mask &= kpos[None, :] <= qpos[:, None]
     if window:
         mask &= (qpos[:, None] - kpos[None, :]) < window
-    s = jnp.where(mask[None, None, None], s, -1e30)
+    if lengths is not None:
+        mask &= kpos[None, None, :] < lengths[:, None, None]
+    s = jnp.where(mask[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskh->bkgqh", p, v.astype(jnp.float32))
-    return jnp.moveaxis(o, 3, 1).reshape(B, Sq, H, hd).astype(q.dtype)
+    o = jnp.moveaxis(o, 3, 1).reshape(B, Sq, H, hd)
+    if lengths is not None:
+        o = jnp.where((qpos[None, :] < lengths[:, None])[..., None, None], o, 0)
+    return o.astype(q.dtype)
 
 
 def decode_attention_ref(

@@ -1,10 +1,10 @@
 """Attention: GQA/MQA projections, blocked (flash-style) XLA attention for
-train/prefill, and KV-cached decode attention (full cache + sliding ring).
+training and the encoder-decoder, and KV-cached decode attention (full cache
++ sliding ring). On TPU the served prefill runs the Pallas kernel in
+``repro.kernels.flash_attention`` instead (``transformer._apply_attn_seq``).
 
 The blocked implementation never materializes an (S, S) score matrix — it
-scans KV blocks with an online softmax, which is both the memory-honest
-lowering for the roofline analysis and the structural twin of the Pallas
-kernel in ``repro.kernels.flash_attention``.
+scans KV blocks with an online softmax, and has a backward pass.
 """
 
 from __future__ import annotations
@@ -171,9 +171,11 @@ def blocked_attention(
             outs.append(q_chunk_attend(q_[:, i], qpos, n_blocks, kb_, vb_, kvb_))
         return jnp.stack(outs, axis=1)  # (B, nq, KV, G, bq, hd)
 
-    # custom_vjp so BOTH passes carry the fusedkernel scope: on TPU this region
-    # is the Pallas flash kernel (fwd) + recompute-based flash bwd kernel; the
-    # roofline analyzer treats scoped intermediates as VMEM-resident.
+    # custom_vjp so BOTH passes carry the fusedkernel scope: this region is
+    # XLA's, forward and a recompute-based backward, and the roofline analyzer
+    # treats its scoped intermediates as VMEM-resident. On TPU the served
+    # prefill does not run it: its attention is the Pallas kernel, under the
+    # same scope.
     core = _scoped_kernel_vjp("fusedkernel_flash_attention", attend_all)
     out = core(q, kb, vb, kvb)
     out = jnp.moveaxis(out, -2, 2).reshape(B, Sqp, KV * G, hd)

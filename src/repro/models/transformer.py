@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.config import ModelConfig
+from repro.kernels import ops as kops
 from repro.models import attention as attn
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (
@@ -176,19 +177,28 @@ def _precompute_angles(cfg: ModelConfig, plan, positions):
 # ---------------------------------------------------------------------------
 
 
-def _apply_attn_seq(lp, x, ctx: Ctx, kind: str, angles, attn_valid):
+def _apply_attn_seq(lp, x, ctx: Ctx, kind: str, angles, attn_valid, lengths):
     cfg = ctx.cfg
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     q, k, v = attn.qkv_project(lp["attn"], h, cfg, angles)
-    o = attn.blocked_attention(
-        q, k, v,
-        causal=True,
-        window=_attn_kind_window(cfg, kind),
-        kv_valid=attn_valid,
-        block_q=ctx.block_q,
-        block_kv=ctx.block_kv,
-        causal_skip=ctx.causal_skip,
-    )
+    window = _attn_kind_window(cfg, kind)
+    if ctx.mode == "prefill" and kops.resolve("auto") != "xla":
+        # on TPU, the Pallas kernel: only the blocks the causal mask and each
+        # row's length leave are computed
+        o = kops.flash_attention(q, k, v, lengths, window=window, impl="auto")
+    else:
+        # training, the encoder-decoder and, off TPU, the prefill (the host's
+        # tests and dry-runs): blocked XLA attention, with a backward pass and
+        # a working set of blocks rather than (S, S) scores
+        o = attn.blocked_attention(
+            q, k, v,
+            causal=True,
+            window=window,
+            kv_valid=attn_valid,
+            block_q=ctx.block_q,
+            block_kv=ctx.block_kv,
+            causal_skip=ctx.causal_skip,
+        )
     x = x + attn.out_project(lp["attn"], o)
     return x, (k, v)
 
@@ -207,7 +217,7 @@ def _apply_ffn(lp, x, ctx: Ctx, ffn_kind: str):
     return x + y, aux
 
 
-def _apply_layer_seq(lp, shared, x, ctx: Ctx, kind: str, angles, attn_valid):
+def _apply_layer_seq(lp, shared, x, ctx: Ctx, kind: str, angles, attn_valid, lengths):
     """Returns (x, cache_entry, aux)."""
     cfg = ctx.cfg
     if kind == "ssm":
@@ -218,7 +228,7 @@ def _apply_layer_seq(lp, shared, x, ctx: Ctx, kind: str, angles, attn_valid):
         y = ssm_mod.ssm_apply_train(lp["ssm"], h, cfg)
         return x + y, None, jnp.zeros((), jnp.float32)
     p = shared if kind == "shared_attn" else lp
-    x, (k, v) = _apply_attn_seq(p, x, ctx, kind, angles, attn_valid)
+    x, (k, v) = _apply_attn_seq(p, x, ctx, kind, angles, attn_valid, lengths)
     x, aux = _apply_ffn(p, x, ctx, "moe" if kind == "moe" else "mlp")
     cache = None
     if ctx.mode == "prefill":
@@ -262,6 +272,8 @@ def forward(
 
     ``logits_mode="none"`` skips the (B, S, V) unembed entirely — serving
     prefill gathers the last-token hidden state and unembeds (B, V) itself.
+    A prefill's rows are right-padded: ``attn_valid`` marks each row's
+    prompt, which attention reads as the row's length.
     """
     ctx = ctx or Ctx(cfg=cfg)
     if embeds is None:
@@ -271,6 +283,9 @@ def forward(
         positions = (
             text_mrope_positions(B, S) if cfg.use_mrope else positions_from_tokens(B, S)
         )
+    lengths = None
+    if ctx.mode == "prefill" and attn_valid is not None:
+        lengths = jnp.sum(attn_valid, axis=-1, dtype=jnp.int32)
     plan = layer_plan(cfg)
     angle_map = _precompute_angles(cfg, plan, positions)
     angles = lambda kind: angle_map["local" if kind == "local" else "global"]
@@ -287,7 +302,7 @@ def forward(
             for j, kind in enumerate(_kinds):
                 lp = block_p[f"layer_{j}"]
                 x, cache, a = _apply_layer_seq(
-                    lp, shared, x, ctx, kind, angles(kind), attn_valid
+                    lp, shared, x, ctx, kind, angles(kind), attn_valid, lengths
                 )
                 if ctx.mode == "prefill":
                     entries[f"layer_{j}"] = cache

@@ -1364,13 +1364,18 @@ class RealEngine:
     is not one), with the counters ``host_syncs`` (device→host reads of the
     decode loop: one per step but the first, two after it, and those
     ``collect_per_step`` and ``logit_steps`` ask for), ``overrun_steps``
-    (steps dispatched after every row had finished, 0 or 1) and
-    ``cache_bytes`` (the bytes of the cache the decode loop holds). The
-    cache is donated to each step, which writes its token in place.
+    (steps dispatched after every row had finished, 0 or 1),
+    ``cache_bytes`` (the bytes of the cache the decode loop holds), and
+    ``prefill_attn_blocks`` / ``prefill_attn_blocks_all``: the (q block, kv
+    block) pairs the prefill's attention kernel computes for one head of one
+    unwindowed layer, summed over the rows, and its full square, counted on
+    the host from the prompt lengths. The cache is donated to each step,
+    which writes its token in place.
 
     Under a profiler, each call also writes the host spans
     ``repro.engine.generate`` (args ``call``, ``B``, ``Sp``, ``max_new``)
-    around ``.prefill``, ``.grow_cache`` (arg ``cache_bytes``) and
+    around ``.prefill`` (args ``attn_blocks``, ``attn_blocks_all``: the two
+    counts), ``.grow_cache`` (arg ``cache_bytes``) and
     ``.decode``; the decode loop holds one ``.step`` (``step_num``) per
     iteration, each around ``.dispatch`` and ``.sync`` (one per device→host
     read), and ends in the final reads' ``.sync``. The decode program is ``jit_decode_step`` and the
@@ -1430,6 +1435,8 @@ class RealEngine:
         import jax.numpy as jnp
         from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
+        from repro.kernels.flash_attention import live_block_count, prefill_blocks
+
         def span(name, **args):
             # about a microsecond when no profiler is running
             return TraceAnnotation(_ENGINE_SPAN + name, **args)
@@ -1454,7 +1461,9 @@ class RealEngine:
             batch = {"tokens": jnp.asarray(prompts),
                      "attn_valid": jnp.asarray(valid)}
             lengths = jnp.asarray(prompt_lens, jnp.int32)
-            with span("prefill"):
+            attn_blocks, attn_blocks_all = live_block_count(
+                prompt_lens, Sp, *prefill_blocks(Sp))
+            with span("prefill", attn_blocks=attn_blocks, attn_blocks_all=attn_blocks_all):
                 t0 = time.perf_counter()
                 cur_logits, phi, cache = jax.block_until_ready(
                     self._jit_prefill(self.params, batch, lengths))
@@ -1511,7 +1520,9 @@ class RealEngine:
                 step_valid[:, steps:] = False
             self.timings.append({"prefill_s": prefill_s, "decode_s": decode_s,
                                  "decode_steps": steps, "host_syncs": host_syncs,
-                                 "overrun_steps": overrun, "cache_bytes": cache_bytes})
+                                 "overrun_steps": overrun, "cache_bytes": cache_bytes,
+                                 "prefill_attn_blocks": attn_blocks,
+                                 "prefill_attn_blocks_all": attn_blocks_all})
             gen_len = np.where(gen_len == 0, self.max_new, gen_len)
             return {"lengths": gen_len,
                     "phi": np.asarray(phi) if collect_hidden else None,

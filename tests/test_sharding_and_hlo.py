@@ -130,6 +130,26 @@ def test_small_mesh_dryrun_lowering(mesh):
     assert stats.flops > 0
 
 
+def test_dryrun_prefill_attention_is_blocked_and_scoped(mesh):
+    """Off TPU the dry-run's prefill keeps blocked attention: its working set
+    stays far below one layer's (B, H, S, S) float32 scores, and it carries
+    the ``fusedkernel_flash_attention`` scope that the roofline reads."""
+    from repro.common.config import InputShape
+    from repro.configs import get_config
+    from repro.launch.workload import build_steps
+
+    cfg = get_config("qwen2.5-7b").reduced()
+    B, S = 2, 4096
+    built = build_steps(cfg, InputShape("tiny_prefill", S, B, "prefill"), mesh=mesh)
+    with mesh:
+        compiled = jax.jit(built["step"], in_shardings=built["arg_shardings"],
+                           out_shardings=built["out_shardings"]).lower(
+                               *built["arg_specs"]).compile()
+    assert 'fusedkernel_flash_attention' in compiled.as_text()
+    scores = B * cfg.n_heads * S * S * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < scores / 4
+
+
 def test_tpu_shardable_cfg_padding():
     from repro.launch.workload import tpu_shardable_cfg
     from repro.configs import get_config

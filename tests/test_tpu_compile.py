@@ -41,8 +41,12 @@ def topo():
 def one_chip(topo):
     """Shape-only arguments on one described chip, compiled with the
     persistent compilation cache off: an executable compiled for a described
-    chip cannot be read back without one."""
+    chip cannot be read back without one. The kernels' ``impl="auto"``
+    dispatch asks the process's backend, which is the CPU here; it is told
+    the TPU's answer, so that the programs compiled are those the chip runs."""
     from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.kernels import ops
 
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -52,7 +56,9 @@ def one_chip(topo):
     def shape(s, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
 
-    yield shape
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(ops, "_on_tpu", lambda: True)
+        yield shape
     jax.config.update("jax_enable_compilation_cache", prev)
     cc.reset_cache()
 
@@ -104,6 +110,23 @@ def test_flash_attention_kernel_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# the served prefill's attention at the cells' shapes: (rows, prompts padded
+# to, heads, K/V heads, head width)
+PREFILL_CELLS = {"qwen25_7b.prefill_long": (4, 4096, 28, 4, 128),
+                 "minicpm_2b.decode_long": (8, 1024, 36, 36, 64)}
+
+
+@pytest.mark.parametrize("cell", sorted(PREFILL_CELLS))
+def test_length_aware_flash_kernel_compiles(one_chip, cell):
+    from repro.kernels.flash_attention import flash_attention_pallas
+
+    b, s, H, KV, hd = PREFILL_CELLS[cell]
+    compiled = jax.jit(flash_attention_pallas).lower(
+        one_chip((b, s, H, hd)), one_chip((b, s, KV, hd)),
+        one_chip((b, s, KV, hd)), one_chip((b,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.fixture(scope="module")
 def served(one_chip):
     """RealEngine's jitted steps over Qwen2.5-7B widths at 2 layers (the
@@ -117,17 +140,65 @@ def served(one_chip):
     return model, params, RealEngine(model, params, max_new=MAX_NEW)
 
 
-def test_served_prefill_step_compiles(served, one_chip):
+@pytest.fixture(scope="module")
+def served_prefill(served, one_chip):
     model, params, eng = served
     batch = {"tokens": one_chip((B, SP), jnp.int32),
              "attn_valid": one_chip((B, SP), jnp.bool_)}
-    compiled = eng._jit_prefill.lower(params, batch,
-                                      one_chip((B,), jnp.int32)).compile()
+    return eng._jit_prefill.lower(params, batch, one_chip((B,), jnp.int32)).compile()
+
+
+def test_served_prefill_step_compiles(served, served_prefill):
+    model, _, _ = served
+    compiled = served_prefill
     _fits(compiled)
     logits, phi, _ = compiled.out_info
     assert logits.shape == (B, model.cfg.vocab_size)
     assert logits.dtype == jnp.float32
     assert phi.shape == (B, model.cfg.d_model)
+
+
+def test_prefill_attention_is_the_kernel_under_its_scope(served_prefill):
+    """The trace's reader marks the flash kernel's custom call with the scope
+    that ``prefill_attention_roofline`` reads, in the compiled prefill."""
+    import re
+    import sys
+    from pathlib import Path
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench.trace import hlo_scopes
+
+    text = served_prefill.as_text()
+    module, marks = hlo_scopes(text, ("fusedkernel_flash_attention",))
+    assert module == "jit_prefill"
+    calls = {re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)
+             for line in text.splitlines() if '"tpu_custom_call"' in line}
+    assert any(name in marks for name in calls), (calls, marks)
+
+
+def test_prefill_attention_relayouts_count_against_the_kernel(served_prefill):
+    """The copies that lay q, k and v out for the flash kernel carry its
+    scope, so that ``prefill_attention_roofline`` counts their time."""
+    import re
+    import sys
+    from pathlib import Path
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench.trace import hlo_scopes
+
+    text = served_prefill.as_text()
+    _, marks = hlo_scopes(text, ("fusedkernel_flash_attention",))
+    defs = dict(re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = \S+ ([\w-]+)\(", text, re.M))
+    (call,) = [line for line in text.splitlines()
+               if '"tpu_custom_call"' in line and "flash_attention" in line]
+    operands = re.search(r"custom-call\(([^)]*)\)", call).group(1)
+    copies = [n for n in re.findall(r"%([\w.\-]+)", operands) if defs.get(n) == "copy"]
+    assert copies, operands
+    assert all(n in marks for n in copies), (copies, marks)
 
 
 def test_served_decode_step_compiles(served, one_chip):
