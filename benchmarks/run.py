@@ -16,7 +16,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="paper-scale splits (slow; default is CPU-fast)")
     ap.add_argument("--only", default=None,
-                    help="comma list: fig1,theory,table1,table23,fig2,serving,online,bins,kernels,roofline")
+                    help="comma list: fig1,theory,table1,table23,fig2,serving,online,bins")
     args = ap.parse_args()
     fast = not args.full
     only = set(args.only.split(",")) if args.only else None
@@ -100,22 +100,6 @@ def main() -> None:
         out = bench_bins_ablation.run(fast=fast)
         for k, v in bench_bins_ablation.validate(out).items():
             emit(f"bins_{k}", v, "")
-
-    if want("kernels"):
-        print("== Kernel micro-benchmarks ==", flush=True)
-        from benchmarks import bench_kernels
-        for name, us in bench_kernels.run().items():
-            emit(name, f"{us:.1f}", "us_per_call (xla/cpu)")
-
-    if want("roofline"):
-        print("== Roofline (from dry-run artifacts) ==", flush=True)
-        from benchmarks import roofline
-        rrows = roofline.load()
-        ok = sum(1 for r in rrows if r.get("ok"))
-        emit("roofline_pod_combos_ok", f"{ok}/{len(rrows)}", "lower+compile on 16x16")
-        mrows = roofline.load(mesh="multipod")
-        mok = sum(1 for r in mrows if r.get("ok"))
-        emit("roofline_multipod_combos_ok", f"{mok}/{len(mrows)}", "2x16x16")
 
     print(f"\ntotal bench time: {time.time()-t_all:.0f}s ({len(rows)} rows)")
 

@@ -3,8 +3,5 @@
 from repro.common.config import (  # noqa: F401
     ModelConfig,
     TrainConfig,
-    ServeConfig,
     PredictorConfig,
-    InputShape,
-    INPUT_SHAPES,
 )

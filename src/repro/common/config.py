@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -196,31 +195,6 @@ class ModelConfig:
 
 
 @dataclass(frozen=True)
-class InputShape:
-    """One of the four assigned workload shapes."""
-
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-
-INPUT_SHAPES: Tuple[InputShape, ...] = (
-    InputShape("train_4k", 4_096, 256, "train"),
-    InputShape("prefill_32k", 32_768, 32, "prefill"),
-    InputShape("decode_32k", 32_768, 128, "decode"),
-    InputShape("long_500k", 524_288, 1, "decode"),
-)
-
-
-def get_input_shape(name: str) -> InputShape:
-    for s in INPUT_SHAPES:
-        if s.name == name:
-            return s
-    raise KeyError(f"unknown input shape {name!r}; options: {[s.name for s in INPUT_SHAPES]}")
-
-
-@dataclass(frozen=True)
 class TrainConfig:
     optimizer: str = "adamw"          # adamw | adafactor
     lr: float = 3e-4
@@ -252,15 +226,4 @@ class PredictorConfig:
     epochs: int = 30
     batch_size: int = 256
     weight_decay: float = 0.0
-    seed: int = 0
-
-
-@dataclass(frozen=True)
-class ServeConfig:
-    max_batch_slots: int = 32
-    max_seq_len: int = 4096
-    scheduler: str = "fcfs"           # fcfs | sjf_pred | sjf_oracle | quantile
-    reserve_quantile: float = 0.9     # KV reservation quantile from ProD-D
-    kv_memory_budget: int = 1 << 24   # tokens of KV the device pool can hold
-    decode_temperature: float = 0.8
     seed: int = 0

@@ -1,8 +1,8 @@
 """Kernel-region scoping that survives autodiff.
 
 ``jax.named_scope`` metadata is lost on ops produced by transpose/jvp
-rewrites, so backward passes of kernel regions would leak into the roofline's
-HBM accounting. ``scoped_kernel_vjp`` wraps a region in a ``custom_vjp`` whose
+rewrites, so backward passes of kernel regions would lose the scope that
+trace readers charge their time to. ``scoped_kernel_vjp`` wraps a region in a ``custom_vjp`` whose
 backward re-traces the region *inside* a scope — which is also the faithful
 model of the real TPU execution: a Pallas forward kernel plus a
 recompute-based backward kernel (flash-attention-style)."""

@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness ground truth).
+"""Pure-jnp oracles for every Pallas kernel (the correctness ground truth),
+and for the served decode attention, ``models.attention.decode_attention``.
 
 Tests sweep shapes/dtypes and ``assert_allclose`` kernel-vs-oracle; the model
 code paths also use these (via ``ops``' ``xla`` impl) on non-TPU backends.
@@ -66,36 +67,6 @@ def decode_attention_ref(
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgs,bskh->bkgh", p, v.astype(jnp.float32))
     return o.reshape(B, H, hd).astype(q.dtype)
-
-
-def ssd_scan_ref(
-    x: jax.Array,    # (B, S, H, P)
-    dt: jax.Array,   # (B, S, H) fp32
-    a: jax.Array,    # (B, S, H) fp32 log-decay
-    Bm: jax.Array,   # (B, S, N)
-    Cm: jax.Array,   # (B, S, N)
-) -> Tuple[jax.Array, jax.Array]:
-    """Sequential SSD recurrence. Returns (y (B,S,H,P), h (B,H,P,N))."""
-    B, S, H, P = x.shape
-    N = Bm.shape[-1]
-
-    def step(h, t):
-        xt, dtt, at, Bt, Ct = t
-        h = jnp.exp(at)[:, :, None, None] * h + jnp.einsum(
-            "bh,bhp,bn->bhpn", dtt, xt, Bt
-        )
-        return h, jnp.einsum("bhpn,bn->bhp", h, Ct)
-
-    h0 = jnp.zeros((B, H, P, N), jnp.float32)
-    xs = (
-        jnp.moveaxis(x, 1, 0).astype(jnp.float32),
-        jnp.moveaxis(dt, 1, 0).astype(jnp.float32),
-        jnp.moveaxis(a, 1, 0).astype(jnp.float32),
-        jnp.moveaxis(Bm, 1, 0).astype(jnp.float32),
-        jnp.moveaxis(Cm, 1, 0).astype(jnp.float32),
-    )
-    h, ys = jax.lax.scan(step, h0, xs)
-    return jnp.moveaxis(ys, 0, 1).astype(x.dtype), h
 
 
 def prod_head_ref(

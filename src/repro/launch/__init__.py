@@ -1,2 +1,1 @@
-"""Launch layer: production meshes, workload input specs, multi-pod dry-run,
-and the train/serve entry points."""
+"""Launch layer: the train and serve entry points."""

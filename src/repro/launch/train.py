@@ -1,7 +1,6 @@
 """Training launcher.
 
-CPU-scale real run (tiny/reduced configs) or production lowering (full
-configs on a TPU mesh — on this container use dryrun.py for full configs).
+CPU-scale real run of the tiny LM or of a reduced per-family config.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch tiny-lm --steps 50

@@ -89,7 +89,6 @@ def blocked_attention(
     kv_valid: Optional[jax.Array] = None,  # (B, Skv) bool
     block_q: int = 512,
     block_kv: int = 512,
-    causal_skip: bool = False,    # skip fully-masked KV blocks (perf variant)
 ) -> jax.Array:
     """Online-softmax blocked attention. Returns (B, Sq, H, hd)."""
     B, Sq, H, hd = q.shape
@@ -114,7 +113,7 @@ def blocked_attention(
     else:
         kvb = None
 
-    def q_chunk_attend(qc, qpos, n_blocks, kb, vb, kvb):
+    def q_chunk_attend(qc, qpos, kb, vb, kvb):
         # qc: (B, bq, KV, G, hd); qpos: (bq,)
         def body(carry, blk):
             m, l, acc = carry
@@ -151,9 +150,9 @@ def blocked_attention(
         a0 = jnp.zeros((B, KV, G, block_q, hd), jnp.float32)
         # rebuilt here (not closed over) so the custom_vjp bwd re-trace is pure
         kpos = jnp.arange(Skvp, dtype=jnp.int32).reshape(nkv, block_kv)
-        xs = (kb[:n_blocks], vb[:n_blocks], kpos[:n_blocks])
+        xs = (kb, vb, kpos)
         if kvb is not None:
-            xs = xs + (kvb[:n_blocks],)
+            xs = xs + (kvb,)
         (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), xs)
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return out  # (B, KV, G, bq, hd)
@@ -162,20 +161,13 @@ def blocked_attention(
         outs = []
         for i in range(nq):
             qpos = q_offset + i * block_q + jnp.arange(block_q, dtype=jnp.int32)
-            if causal and causal_skip and kv_valid is None and window == 0:
-                # only KV blocks whose first position is visible to this chunk
-                last_q = q_offset + (i + 1) * block_q - 1
-                n_blocks = min(nkv, max(1, -(-min(last_q + 1, Skv0) // block_kv)))
-            else:
-                n_blocks = nkv
-            outs.append(q_chunk_attend(q_[:, i], qpos, n_blocks, kb_, vb_, kvb_))
+            outs.append(q_chunk_attend(q_[:, i], qpos, kb_, vb_, kvb_))
         return jnp.stack(outs, axis=1)  # (B, nq, KV, G, bq, hd)
 
     # custom_vjp so BOTH passes carry the fusedkernel scope: this region is
-    # XLA's, forward and a recompute-based backward, and the roofline analyzer
-    # treats its scoped intermediates as VMEM-resident. On TPU the served
+    # XLA's, forward and a recompute-based backward. On TPU the served
     # prefill does not run it: its attention is the Pallas kernel, under the
-    # same scope.
+    # same scope, which the benchmark's trace reader charges to attention.
     core = _scoped_kernel_vjp("fusedkernel_flash_attention", attend_all)
     out = core(q, kb, vb, kvb)
     out = jnp.moveaxis(out, -2, 2).reshape(B, Sqp, KV * G, hd)

@@ -69,8 +69,7 @@ def sinusoid_at(positions: jax.Array, d_model: int) -> jax.Array:
     return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
 
 
-def encode(params, cfg: ModelConfig, enc_embeds: jax.Array,
-           block_q: int = 512, block_kv: int = 512) -> jax.Array:
+def encode(params, cfg: ModelConfig, enc_embeds: jax.Array) -> jax.Array:
     """Bidirectional encoder over stub frame embeddings (B, Senc, d)."""
     B, S, d = enc_embeds.shape
     x = enc_embeds + sinusoid_at(jnp.arange(S), d)[None].astype(enc_embeds.dtype)
@@ -78,8 +77,7 @@ def encode(params, cfg: ModelConfig, enc_embeds: jax.Array,
     def layer(x, lp):
         h = _ln(lp["ln1"], x, cfg.norm_eps)
         q, k, v = attn.qkv_project(lp["attn"], h, cfg, None)
-        o = attn.blocked_attention(q, k, v, causal=False,
-                                   block_q=block_q, block_kv=block_kv)
+        o = attn.blocked_attention(q, k, v, causal=False)
         x = x + attn.out_project(lp["attn"], o)
         h = _ln(lp["ln2"], x, cfg.norm_eps)
         return x + mlp_apply(lp["mlp"], h, "gelu"), None
@@ -90,8 +88,8 @@ def encode(params, cfg: ModelConfig, enc_embeds: jax.Array,
 
 def decoder_forward(
     params, cfg: ModelConfig, tokens: jax.Array, enc_out: jax.Array,
-    mode: str = "train", block_q: int = 512, block_kv: int = 512,
-    attn_valid: Optional[jax.Array] = None, logits_mode: str = "all",
+    mode: str = "train", attn_valid: Optional[jax.Array] = None,
+    logits_mode: str = "all",
 ):
     """Causal decoder with cross-attention. Returns (logits, hidden, cache)."""
     B, S = tokens.shape
@@ -101,13 +99,11 @@ def decoder_forward(
     def layer(x, lp):
         h = _ln(lp["ln1"], x, cfg.norm_eps)
         q, k, v = attn.qkv_project(lp["self_attn"], h, cfg, None)
-        o = attn.blocked_attention(q, k, v, causal=True, kv_valid=attn_valid,
-                                   block_q=block_q, block_kv=block_kv)
+        o = attn.blocked_attention(q, k, v, causal=True, kv_valid=attn_valid)
         x = x + attn.out_project(lp["self_attn"], o)
         h = _ln(lp["ln2"], x, cfg.norm_eps)
         qc, kc, vc = _cross_qkv(lp["cross_attn"], h, enc_out, cfg)
-        oc = attn.blocked_attention(qc, kc, vc, causal=False,
-                                    block_q=block_q, block_kv=block_kv)
+        oc = attn.blocked_attention(qc, kc, vc, causal=False)
         x = x + attn.out_project(lp["cross_attn"], oc)
         h = _ln(lp["ln3"], x, cfg.norm_eps)
         x = x + mlp_apply(lp["mlp"], h, "gelu")

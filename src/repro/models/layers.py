@@ -5,8 +5,7 @@ A model is described once as a *spec tree* — nested dicts with
 From the single spec we derive:
 
 * ``init_tree``   — materialized parameters (smoke tests, tiny-LM runs),
-* ``shape_tree``  — ShapeDtypeStructs (multi-pod dry-run: zero allocation),
-* ``axes_tree``   — logical-axis tuples (resolved to NamedShardings at launch).
+* ``shape_tree``  — ShapeDtypeStructs (shape-only lowering: zero allocation).
 """
 
 from __future__ import annotations
@@ -66,10 +65,6 @@ def shape_tree(spec_tree: Any, dtype) -> Any:
         spec_tree,
         is_leaf=_is_spec,
     )
-
-
-def axes_tree(spec_tree: Any) -> Any:
-    return jax.tree_util.tree_map(lambda s: s.axes, spec_tree, is_leaf=_is_spec)
 
 
 def stack_specs(spec_tree: Any, n: int, axis_name: str = "layers") -> Any:

@@ -7,8 +7,8 @@ of (params, batch) suitable for jit/pjit:
 * ``prefill(params, batch, rt)``      — logits, last-layer hidden states, KV/SSM
                                         cache, aux (serving prefill path)
 * ``decode_step(params, batch, cache, rt)`` — one new token vs. the cache
-* ``init(key)/param_shapes()/param_axes()/cache_specs(...)`` — materialized or
-  shape-only parameters with logical sharding axes.
+* ``init(key)/param_shapes()/cache_specs(...)`` — materialized or
+  shape-only parameters and caches.
 
 The ProD predictor head consumes ``hidden`` from prefill/decode — i.e. the
 served model's last-layer hidden state, per the paper (§2.4).
@@ -24,35 +24,19 @@ import jax.numpy as jnp
 
 from repro.common.config import ModelConfig
 from repro.models import encdec, transformer
-from repro.models.layers import axes_tree, init_tree, shape_tree
+from repro.models.layers import init_tree, shape_tree
 from repro.models.transformer import Ctx
 
 
 @dataclass(frozen=True)
 class Runtime:
-    """Execution-environment knobs threaded through model calls.
-
-    Performance-iteration knobs (see EXPERIMENTS.md §Perf):
-    * ``causal_skip``   — skip fully-masked KV blocks in blocked attention
-    * ``moe_cap_slack`` — multiplier on the MoE expert capacity (imbalance headroom)
-    * ``moe_fsdp_mode`` — "gather" (all-gather expert weights per layer) or
-                          "partial" (d-sliced partial matmuls + activation psum;
-                          the decode-friendly choice)
-    * ``kv_quant``      — int8 KV cache with per-(token, head) scales (decode)
-    * ``seq_shard``     — shard the residual stream's seq dim over `model`
-                          between layers (Megatron sequence parallelism)
-    """
+    """Execution-environment settings threaded through model calls: the
+    device mesh (None on one device), rematerialization in training, and the
+    MoE expert capacity factor (0 drops no token)."""
 
     mesh: Any = None
     remat: str = "none"
     capacity_factor: float = 1.25
-    block_q: int = 512
-    block_kv: int = 512
-    causal_skip: bool = False
-    moe_cap_slack: float = 2.0
-    moe_fsdp_mode: str = "gather"
-    kv_quant: bool = False
-    seq_shard: bool = False
 
     @staticmethod
     def local() -> "Runtime":
@@ -62,13 +46,7 @@ class Runtime:
         return Ctx(
             cfg=cfg, mesh=self.mesh, mode=mode,
             remat=self.remat if mode == "train" else "none",
-            block_q=self.block_q, block_kv=self.block_kv,
-            causal_skip=self.causal_skip,
             capacity_factor=self.capacity_factor,
-            moe_cap_slack=self.moe_cap_slack,
-            moe_fsdp_mode=self.moe_fsdp_mode,
-            kv_quant=self.kv_quant,
-            seq_shard=self.seq_shard,
         )
 
 
@@ -94,9 +72,6 @@ class Model:
     def param_shapes(self, dtype=None):
         return shape_tree(self.spec(), dtype or self.cfg.dtype)
 
-    def param_axes(self):
-        return axes_tree(self.spec())
-
     # -- training -----------------------------------------------------------
     def loss(self, params, batch: Dict[str, jax.Array], rt: Runtime):
         cfg = self.cfg
@@ -117,11 +92,9 @@ class Model:
         """Returns (logits, hidden (B,S,d), cache, aux)."""
         cfg = self.cfg
         if cfg.family == "encdec":
-            enc_out = encdec.encode(params, cfg, batch["enc_embeds"],
-                                    block_q=rt.block_q, block_kv=rt.block_kv)
+            enc_out = encdec.encode(params, cfg, batch["enc_embeds"])
             logits, hidden, cache = encdec.decoder_forward(
                 params, cfg, batch["tokens"], enc_out, mode="prefill",
-                block_q=rt.block_q, block_kv=rt.block_kv,
                 attn_valid=batch.get("attn_valid"), logits_mode=logits_mode,
             )
             return logits, hidden, cache, jnp.zeros((), jnp.float32)
@@ -146,34 +119,16 @@ class Model:
         return logits, hidden, new_cache
 
     # -- caches --------------------------------------------------------------
-    def cache_specs(self, batch: int, cache_len: int, kv_quant: bool = False):
+    def cache_specs(self, batch: int, cache_len: int):
         if self.cfg.family == "encdec":
             return encdec.decoder_cache_spec(self.cfg, batch, cache_len)
-        return transformer.cache_spec(self.cfg, batch, cache_len,
-                                      kv_quant=kv_quant)
+        return transformer.cache_spec(self.cfg, batch, cache_len)
 
-    def cache_shapes(self, batch: int, cache_len: int, dtype=None,
-                     kv_quant: bool = False):
+    def cache_shapes(self, batch: int, cache_len: int, dtype=None):
         dt = dtype or self.cfg.dtype
         return jax.tree_util.tree_map(
             lambda s: jax.ShapeDtypeStruct(s.shape, transformer.cache_dtype(s, dt)),
-            self.cache_specs(batch, cache_len, kv_quant),
-            is_leaf=lambda x: hasattr(x, "axes") and hasattr(x, "init"),
-        )
-
-    def cache_axes(self, kv_quant: bool = False):
-        # axes trees match cache_specs structure
-        return jax.tree_util.tree_map(
-            lambda s: s.axes, self.cache_specs(1, 2, kv_quant),
-            is_leaf=lambda x: hasattr(x, "axes") and hasattr(x, "init"),
-        )
-
-    def init_cache(self, batch: int, cache_len: int, dtype=None,
-                   kv_quant: bool = False):
-        dt = dtype or self.cfg.dtype
-        return jax.tree_util.tree_map(
-            lambda s: jnp.zeros(s.shape, transformer.cache_dtype(s, dt)),
-            self.cache_specs(batch, cache_len, kv_quant),
+            self.cache_specs(batch, cache_len),
             is_leaf=lambda x: hasattr(x, "axes") and hasattr(x, "init"),
         )
 

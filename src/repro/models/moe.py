@@ -61,6 +61,11 @@ def _router(logits32: jax.Array, k: int) -> Tuple[jax.Array, jax.Array, jax.Arra
     return top_ids, combine, aux
 
 
+# headroom on the per-shard expert capacity under expert parallelism, for the
+# imbalance of experts across shards
+CAP_SLACK = 2.0
+
+
 def _capacity(T: int, k: int, E_local: int, factor: float) -> int:
     cap = int(T * k * factor / max(E_local, 1)) + 1
     return max(8, min(cap, T * k))
@@ -127,8 +132,6 @@ def moe_apply(
     mesh=None,
     capacity_factor: float = 1.25,
     batch_spec: Optional[P] = None,
-    cap_slack: float = 2.0,
-    fsdp_mode: str = "gather",    # gather | partial (see Runtime docstring)
 ) -> Tuple[jax.Array, jax.Array]:
     """MoE FFN. Returns (out (B,S,d), aux_loss scalar)."""
     B, S, d = x.shape
@@ -165,9 +168,9 @@ def moe_apply(
             xspec = P(None, None)  # decode batch=1: tokens replicated
             tokens_sharded = False
         # capacity is per SHARD-LOCAL tokens (computing it on the global T
-        # over-allocated the expert matmuls 16x — see EXPERIMENTS.md §Perf)
+        # over-allocates the expert matmuls by the data-parallel degree)
         T_loc = T // n_data if tokens_sharded else T
-        cap = int(_capacity(T_loc, k, E, cf) * cap_slack)
+        cap = int(_capacity(T_loc, k, E, cf) * CAP_SLACK)
         cap = max(8, min(cap, T_loc * k))
         # expert weights stored d-sharded over the data axes (FSDP) and
         # expert-sharded over model; gathered per layer inside the shard_map
@@ -179,8 +182,8 @@ def moe_apply(
             return jnp.where((ids >= lo) & (ids < lo + E_local), ids - lo, E_local)
 
         def shard_fn(xf_l, ids_l, cw_l, wg_l, wu_l, wd_l):
-            """FSDP mode "gather": all-gather the d-sharded expert weights per
-            layer (train-friendly: weight traffic amortized over many tokens)."""
+            """All-gather the d-sharded expert weights per layer (weight
+            traffic amortized over many tokens)."""
             if fsdp:
                 wg_l = jax.lax.all_gather(wg_l, fsdp, axis=1, tiled=True)
                 wu_l = jax.lax.all_gather(wu_l, fsdp, axis=1, tiled=True)
@@ -189,48 +192,8 @@ def moe_apply(
                                       wg_l, wu_l, wd_l, cap)
             return jax.lax.psum(out_l, "model")
 
-        def shard_fn_partial(xf_l, ids_l, cw_l, wg_l, wu_l, wd_l):
-            """FSDP mode "partial": weights stay d-sharded; tokens are gathered
-            over the data axes (tiny at decode), pre-activations are partial-
-            summed. Weight traffic: ZERO; activation traffic ~ O(tokens×f).
-            The decode-friendly choice (weights ≫ activations)."""
-            n_fsdp = 1
-            didx = jnp.zeros((), jnp.int32)
-            for a in fsdp:
-                n_fsdp *= int(mesh.shape[a])
-                didx = didx * int(mesh.shape[a]) + jax.lax.axis_index(a)
-            T_l = xf_l.shape[0]
-            if fsdp:
-                x_g = jax.lax.all_gather(xf_l, fsdp, axis=0, tiled=True)
-                ids_g = jax.lax.all_gather(ids_l, fsdp, axis=0, tiled=True)
-                cw_g = jax.lax.all_gather(cw_l, fsdp, axis=0, tiled=True)
-            else:
-                x_g, ids_g, cw_g = xf_l, ids_l, cw_l
-            T_g = x_g.shape[0]
-            d_l = d // n_fsdp
-            x_slice = jax.lax.dynamic_slice_in_dim(x_g, didx * d_l, d_l, 1)
-            cap_g = max(8, min(int(_capacity(T_g, k, E, cf) * cap_slack),
-                               T_g * k))
-            gtok, valid, weight = _dispatch(_local_ids(ids_g), cw_g, E_local,
-                                            cap_g)
-            xe = jnp.where(valid[..., None], jnp.take(x_slice, gtok, axis=0), 0)
-            pre_g = jax.lax.psum(jnp.einsum("ecd,edf->ecf", xe, wg_l), fsdp) \
-                if fsdp else jnp.einsum("ecd,edf->ecf", xe, wg_l)
-            pre_u = jax.lax.psum(jnp.einsum("ecd,edf->ecf", xe, wu_l), fsdp) \
-                if fsdp else jnp.einsum("ecd,edf->ecf", xe, wu_l)
-            h = jax.nn.silu(pre_g) * pre_u
-            ye = jnp.einsum("ecf,efd->ecd", h, wd_l)       # (E_l, cap_g, d_l)
-            out_g = _combine(ye, gtok, valid, weight, T_g, d_l)  # (T_g, d_l)
-            if fsdp:
-                out_full = jax.lax.all_gather(out_g, fsdp, axis=1, tiled=True)
-                out_l = jax.lax.dynamic_slice_in_dim(out_full, didx * T_l, T_l, 0)
-            else:
-                out_l = out_g
-            return jax.lax.psum(out_l, "model")
-
-        fn = shard_fn_partial if fsdp_mode == "partial" else shard_fn
         out = jax.shard_map(
-            fn,
+            shard_fn,
             mesh=mesh,
             in_specs=(xspec, xspec, xspec, wspec_up, wspec_up, wspec_dn),
             out_specs=xspec,

@@ -2,8 +2,7 @@
 
 Training/prefill uses the chunked SSD form: a `lax.scan` over sequence chunks
 carrying the (B, H, P, N) inter-chunk state; within a chunk the computation is
-attention-like matmuls (MXU-friendly — this is the part mirrored by the
-Pallas kernel in ``repro.kernels.ssd_scan``). Decode is the O(1) recurrence.
+attention-like matmuls (MXU-friendly). Decode is the O(1) recurrence.
 
 Layout: d_inner = H*P, single B/C group shared across heads (G=1).
 """
@@ -101,8 +100,6 @@ def ssd_chunked(
     if h0 is None:
         h0 = jnp.zeros((B, H, P, N), jnp.float32)
 
-    # runs as the Pallas SSD kernel on TPU (repro.kernels.ssd_scan); the
-    # roofline analyzer treats intermediates inside this scope as VMEM-resident
     def body(h, inp):
         x_c, dt_c, a_c, B_c, C_c = inp          # (B,Q,H,P), (B,Q,H), ..., (B,Q,N)
         cum = jnp.cumsum(a_c, axis=1)           # (B,Q,H)

@@ -144,16 +144,7 @@ class Ctx:
     mesh: Any = None
     mode: str = "train"              # train | prefill | decode
     remat: str = "none"              # none | full
-    block_q: int = 512
-    block_kv: int = 512
-    causal_skip: bool = False
     capacity_factor: float = 1.25
-    moe_cap_slack: float = 2.0
-    moe_fsdp_mode: str = "gather"
-    kv_quant: bool = False
-    seq_shard: bool = False
-    cache_len: int = 0               # decode: allocated full-cache length
-    window_cache_len: int = 0        # decode: allocated ring length
 
 
 def _angles_for(cfg: ModelConfig, positions, kind: str):
@@ -188,17 +179,10 @@ def _apply_attn_seq(lp, x, ctx: Ctx, kind: str, angles, attn_valid, lengths):
         o = kops.flash_attention(q, k, v, lengths, window=window, impl="auto")
     else:
         # training, the encoder-decoder and, off TPU, the prefill (the host's
-        # tests and dry-runs): blocked XLA attention, with a backward pass and
+        # tests): blocked XLA attention, with a backward pass and
         # a working set of blocks rather than (S, S) scores
-        o = attn.blocked_attention(
-            q, k, v,
-            causal=True,
-            window=window,
-            kv_valid=attn_valid,
-            block_q=ctx.block_q,
-            block_kv=ctx.block_kv,
-            causal_skip=ctx.causal_skip,
-        )
+        o = attn.blocked_attention(q, k, v, causal=True, window=window,
+                                   kv_valid=attn_valid)
     x = x + attn.out_project(lp["attn"], o)
     return x, (k, v)
 
@@ -209,9 +193,7 @@ def _apply_ffn(lp, x, ctx: Ctx, ffn_kind: str):
     h = rms_norm(x, lp["ln2"], cfg.norm_eps)
     if ffn_kind == "moe":
         y, aux = moe_apply(lp["moe"], h, cfg, mesh=ctx.mesh,
-                           capacity_factor=ctx.capacity_factor,
-                           cap_slack=ctx.moe_cap_slack,
-                           fsdp_mode=ctx.moe_fsdp_mode)
+                           capacity_factor=ctx.capacity_factor)
     else:
         y = mlp_apply(lp["mlp"], h, cfg.act)
     return x + y, aux
@@ -307,17 +289,6 @@ def forward(
                 if ctx.mode == "prefill":
                     entries[f"layer_{j}"] = cache
                 aux = aux + a
-            if ctx.seq_shard and ctx.mesh is not None:
-                # Megatron sequence parallelism: the saved residual (and the
-                # norm/elementwise region around it) lives seq-sharded over
-                # `model`; GSPMD inserts all-gather at attention entry and
-                # reduce-scatter after — trades collective for 16× less saved
-                # activation memory under remat
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                data_axes = tuple(a for a in ("pod", "data")
-                                  if a in ctx.mesh.axis_names)
-                x = jax.lax.with_sharding_constraint(
-                    x, NamedSharding(ctx.mesh, P(data_axes, "model", None)))
             return (x, aux), entries
 
         fn = jax.checkpoint(block_fn) if ctx.remat == "full" else block_fn
@@ -336,16 +307,13 @@ def forward(
 # ---------------------------------------------------------------------------
 
 
-def cache_spec(cfg: ModelConfig, batch: int, cache_len: int,
-               kv_quant: bool = False):
+def cache_spec(cfg: ModelConfig, batch: int, cache_len: int):
     """ParamSpec pytree (shapes + logical axes) for the decode cache.
 
     Windowed layers allocate a ring of ``min(window, cache_len)``; full layers
     allocate ``cache_len``. K/V are (layers, batch, seq, KV·hd): heads and
     head width merged, lane-dense for every head width. SSM layers carry
-    (h, conv) state. With ``kv_quant`` the K/V tensors are int8 with
-    per-(token, kv-head) fp32 scales (beyond-paper serving optimization —
-    halves decode cache reads).
+    (h, conv) state.
     """
     plan = layer_plan(cfg)
     H, P, N, d_conv = (0, 0, 0, 0)
@@ -368,50 +336,24 @@ def cache_spec(cfg: ModelConfig, batch: int, cache_len: int,
                 W = _attn_kind_window(cfg, kind)
                 Sc = min(W, cache_len) if W else cache_len
                 kv_shape = (n, batch, Sc, cfg.n_kv_heads * cfg.head_dim)
-                e = {
-                    "k": ParamSpec(kv_shape, kv_ax,
-                                   init="int8" if kv_quant else "normal"),
-                    "v": ParamSpec(kv_shape, kv_ax,
-                                   init="int8" if kv_quant else "normal"),
-                }
-                if kv_quant:
-                    e["k_s"] = ParamSpec((n, batch, Sc, cfg.n_kv_heads), kv_ax,
-                                         init="f32")
-                    e["v_s"] = ParamSpec((n, batch, Sc, cfg.n_kv_heads), kv_ax,
-                                         init="f32")
-                entries[f"layer_{j}"] = e
+                entries[f"layer_{j}"] = {"k": ParamSpec(kv_shape, kv_ax),
+                                         "v": ParamSpec(kv_shape, kv_ax)}
         segs.append(entries)
     return segs
 
 
 def cache_dtype(spec: ParamSpec, dtype):
-    """SSM h-state + quant scales fp32; int8 for quantized K/V; model dtype else."""
-    if spec.init == "int8":
-        return jnp.int8
-    if spec.init == "f32" or spec.axes[2:3] == ("ssm_heads",):
+    """SSM h-state fp32; model dtype else."""
+    if spec.axes[2:3] == ("ssm_heads",):
         return jnp.float32
     return jnp.dtype(dtype)
-
-
-def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype):
-    spec = cache_spec(cfg, batch, cache_len)
-    return jax.tree_util.tree_map(
-        lambda s: jnp.zeros(s.shape, cache_dtype(s, dtype)), spec,
-        is_leaf=lambda x: isinstance(x, ParamSpec))
-
-
-def _quantize_kv(t):
-    """(B, KV, hd) -> (int8 values, fp32 scales (B, KV))."""
-    scale = jnp.max(jnp.abs(t.astype(jnp.float32)), axis=-1) / 127.0
-    q = jnp.round(t.astype(jnp.float32) / jnp.maximum(scale[..., None], 1e-8))
-    return jnp.clip(q, -127, 127).astype(jnp.int8), scale
 
 
 def _decode_attn_layer(lp, x, entry, i, ctx: Ctx, kind: str, pos, lengths, angles):
     """One cached attention layer for a single new token.
 
     ``entry`` is the segment's stacked cache for this layer position,
-    (n_blocks, B, Sc, ·); the token's K/V (and int8 scales) are written into
+    (n_blocks, B, Sc, ·); the token's K/V are written into
     it in place at ``[i, row, slot]``, then block ``i``'s slice is read for
     attention. Returns (x, the updated stacked entry)."""
     cfg = ctx.cfg
@@ -422,20 +364,12 @@ def _decode_attn_layer(lp, x, entry, i, ctx: Ctx, kind: str, pos, lengths, angle
     Sc = entry["k"].shape[2]
     ring = bool(W) and Sc == W  # ring iff allocated exactly the window
     bidx = jnp.arange(B)
-    quant = "k_s" in entry
-    if quant:
-        k_new, ks_new = _quantize_kv(k[:, 0])
-        v_new, vs_new = _quantize_kv(v[:, 0])
-    else:
-        k_new, v_new = k[:, 0], v[:, 0]
+    k_new, v_new = k[:, 0], v[:, 0]
     slot = jnp.mod(pos, Sc) if ring else pos
     new_entry = {
         "k": entry["k"].at[i, bidx, slot].set(attn.kv_lanes(k_new).astype(entry["k"].dtype)),
         "v": entry["v"].at[i, bidx, slot].set(attn.kv_lanes(v_new).astype(entry["v"].dtype)),
     }
-    if quant:
-        new_entry["k_s"] = entry["k_s"].at[i, bidx, slot].set(ks_new)
-        new_entry["v_s"] = entry["v_s"].at[i, bidx, slot].set(vs_new)
     kc, vc = new_entry["k"][i], new_entry["v"][i]
     if ring:
         valid = attn.ring_cache_valid(lengths, Sc)
@@ -444,18 +378,6 @@ def _decode_attn_layer(lp, x, entry, i, ctx: Ctx, kind: str, pos, lengths, angle
         if W:  # windowed semantics on a full cache
             kpos = jnp.arange(Sc, dtype=jnp.int32)[None, :]
             valid = valid & ((pos[:, None] - kpos) < W)
-    if quant:
-        # dequant INSIDE the kernel scope: on TPU the Pallas decode kernel
-        # reads int8 + scales from HBM and dequantizes in VMEM
-        with jax.named_scope("fusedkernel_decode_attention_dequant"):
-            hd = cfg.head_dim
-
-            def dequant(c, s):
-                c = c.reshape(*s.shape, hd).astype(jnp.float32) * s[..., None]
-                return attn.kv_lanes(c).astype(cfg.dtype)
-
-            kc = dequant(kc, new_entry["k_s"][i])
-            vc = dequant(vc, new_entry["v_s"][i])
     o = attn.decode_attention(q, kc, vc, valid)
     x = x + attn.out_project(lp["attn"], o)
     return x, new_entry
@@ -465,7 +387,7 @@ def decode_step(
     params: Dict[str, Any],
     cfg: ModelConfig,
     tokens: jax.Array,                 # (B,) int32 — the new token
-    cache: Any,                        # pytree from init_cache / prefill
+    cache: Any,                        # pytree of cache_spec's shapes
     pos: jax.Array,                    # (B,) int32 — position of the new token
     lengths: jax.Array,                # (B,) int32 — length AFTER this token
     ctx: Optional[Ctx] = None,

@@ -1310,7 +1310,7 @@ class SimEngine:
 SPAN_PREFIX = "repro."
 _ENGINE_SPAN = SPAN_PREFIX + "engine."
 # the cache leaves with a sequence axis (axis 2), which _grow_cache pads
-_KV_LEAVES = ("k", "v", "k_s", "v_s")
+_KV_LEAVES = ("k", "v")
 
 
 def _draw_then_decode(model_step, eos: int):
@@ -1534,7 +1534,7 @@ class RealEngine:
         import jax.tree_util as jtu
 
         def grow(path, x):
-            # attention K/V (and int8 scales): (layers, B, S, ·) with S ==
+            # attention K/V: (layers, B, S, ·) with S ==
             # old_len; ring caches are allocated at their window and left
             # alone, SSM state has no sequence axis
             if getattr(path[-1], "key", None) in _KV_LEAVES and x.shape[2] == old_len:

@@ -2,8 +2,7 @@
 
 * AdamW — standard decoupled weight decay.
 * Adafactor — factored second moment (rank-1 row/col stats for matrices);
-  the memory-frugal choice that lets the 1T MoE train config fit (see
-  EXPERIMENTS.md §Dry-run).
+  the memory-frugal choice for the largest (1T MoE) train config.
 * Schedules — cosine, constant, and **WSD** (warmup–stable–decay), the
   MiniCPM schedule [arXiv:2404.06395].
 """

@@ -1,5 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS device-count override here — smoke
-tests and benches must see the real 1-CPU default (the dry-run sets its own)."""
+tests and benches must see the real 1-CPU default."""
 
 import jax
 import pytest

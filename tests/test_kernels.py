@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs pure-jnp oracle."""
+"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs pure-jnp oracle,
+and the served XLA decode attention and chunked SSD scan vs theirs."""
 
 import jax
 import jax.numpy as jnp
@@ -8,6 +9,8 @@ import pytest
 from repro.kernels import ops, ref
 from repro.kernels import flash_attention
 from repro.kernels.flash_attention import live_block_count
+from repro.models import attention as attn
+from repro.models import ssm
 
 
 def _tol(dtype):
@@ -114,14 +117,18 @@ def test_flash_attention_fetches_only_live_kv_chunks(length, window):
 @pytest.mark.parametrize("B,Sc,H,KV,hd", [(2, 100, 8, 2, 64), (1, 40, 4, 4, 32)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention_sweep(B, Sc, H, KV, hd, dtype):
+    """The served decode attention, on a lane-dense cache (heads and head
+    width merged), against the oracle on the per-head layout."""
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (B, H, hd), dtype)
     k = jax.random.normal(ks[1], (B, Sc, KV, hd), dtype)
     v = jax.random.normal(ks[2], (B, Sc, KV, hd), dtype)
     lens = jnp.asarray(np.random.default_rng(0).integers(1, Sc + 1, B), jnp.int32)
-    out = ops.decode_attention(q, k, v, lens, block_kv=32, impl="interpret")
+    out = attn.decode_attention(q[:, None], attn.kv_lanes(k), attn.kv_lanes(v),
+                                attn.full_cache_valid(lens, Sc))
+    assert out.shape == (B, 1, H, hd) and out.dtype == dtype
     want = ref.decode_attention_ref(q, k, v, lens)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
+    np.testing.assert_allclose(np.asarray(out[:, 0], np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
 
 
@@ -131,14 +138,16 @@ def test_decode_attention_sweep(B, Sc, H, KV, hd, dtype):
     (1, 17, 4, 16, 32, 8),
 ])
 def test_ssd_scan_sweep(B, S, H, P, N, chunk):
+    """The chunked SSD scan, with a chunk that divides S or not, against the
+    sequential recurrence."""
     ks = jax.random.split(jax.random.PRNGKey(2), 5)
     x = jax.random.normal(ks[0], (B, S, H, P))
     dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
     a = -dt * jnp.exp(0.3 * jax.random.normal(ks[2], (H,)))
     Bm = jax.random.normal(ks[3], (B, S, N))
     Cm = jax.random.normal(ks[4], (B, S, N))
-    y, h = ops.ssd_scan(x, dt, a, Bm, Cm, chunk=chunk, impl="interpret")
-    y2, h2 = ref.ssd_scan_ref(x, dt, a, Bm, Cm)
+    y, h = ssm.ssd_chunked(x, dt, a, Bm, Cm, chunk)
+    y2, h2 = ssm.ssd_reference(x, dt, a, Bm, Cm)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y2), rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(h), np.asarray(h2), rtol=2e-4, atol=2e-4)
 

@@ -93,7 +93,12 @@ def test_reduced_prefill_shapes_and_phi(arch):
 
 @pytest.mark.parametrize("arch", ["yi-34b", "gemma3-27b", "mamba2-130m",
                                   "zamba2-1.2b", "kimi-k2-1t-a32b",
-                                  "whisper-large-v3", "qwen2-vl-2b"])
+                                  "whisper-large-v3", "qwen2-vl-2b",
+                                  # the served configurations, the paper's
+                                  # llama3-8b, and one of each remaining kind
+                                  "qwen2.5-7b", "minicpm-2b", "llama3-8b",
+                                  "granite-20b", "qwen3-moe-235b-a22b",
+                                  "tiny-lm"])
 def test_decode_matches_forward(arch):
     """prefill(S) + decode_step(token S) == forward(S+1) at position S."""
     cfg, m, params = _reduced_model(arch)

@@ -1,11 +1,10 @@
-"""RoPE/M-RoPE properties and workload-builder rules."""
+"""RoPE/M-RoPE properties and the decode cache's layout rules."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.common.config import INPUT_SHAPES, get_input_shape
 from repro.models import rope
 
 
@@ -48,52 +47,7 @@ class TestRope:
                                    np.linalg.norm(np.asarray(xr)), rtol=1e-5)
 
 
-class FakeMesh:
-    def __init__(self, **axes):
-        self.axis_names = tuple(axes)
-        self.shape = dict(axes)
-
-
 class TestWorkloadRules:
-    def test_cfg_for_shape_long_context(self):
-        from repro.configs import get_config
-        from repro.launch.workload import cfg_for_shape
-        long = get_input_shape("long_500k")
-        yi = cfg_for_shape(get_config("yi-34b"), long)
-        assert yi.attn_window == 8192          # windowed decode variant
-        gm = cfg_for_shape(get_config("gemma3-27b"), long)
-        assert gm.local_global_ratio == 5      # native pattern untouched
-        mb = cfg_for_shape(get_config("mamba2-130m"), long)
-        assert mb.attn_window == 0             # attention-free
-        tr = cfg_for_shape(get_config("yi-34b"), get_input_shape("train_4k"))
-        assert tr.attn_window == 0             # train keeps full attention
-
-    def test_input_specs_shapes(self):
-        from repro.configs import get_config
-        from repro.launch.workload import input_specs
-        cfg = get_config("yi-34b")
-        for shape in INPUT_SHAPES:
-            specs, axes = input_specs(cfg, shape)
-            assert set(specs) == set(axes)
-            if shape.kind == "decode":
-                assert specs["tokens"].shape == (shape.global_batch,)
-            else:
-                assert specs["tokens"].shape == (shape.global_batch,
-                                                 shape.seq_len)
-        # whisper gets encoder embeds; vlm gets embeds+positions(+tokens)
-        wspecs, _ = input_specs(get_config("whisper-large-v3"),
-                                get_input_shape("train_4k"))
-        assert wspecs["enc_embeds"].shape == (256, 1500, 1280)
-        vspecs, _ = input_specs(get_config("qwen2-vl-2b"),
-                                get_input_shape("train_4k"))
-        assert "embeds" in vspecs and "positions" in vspecs and "tokens" in vspecs
-
-    def test_variant_registry(self):
-        from repro.launch.workload import VARIANTS
-        for name in ("baseline", "causal_skip", "moe_tight", "moe_partial",
-                     "int8kv", "seqpar", "train_tight", "decode_opt", "nopad"):
-            assert name in VARIANTS
-
     def test_gemma_cache_is_pattern_grouped(self):
         """Ring caches for local layers, full caches only for global layers —
         the memory design that makes 500k-context gemma fit."""

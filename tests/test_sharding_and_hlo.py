@@ -1,4 +1,4 @@
-"""Sharding-rule resolution, HLO analyzer, and a small-mesh dry-run smoke."""
+"""Sharding-rule resolution, and the host prefill's blocked attention."""
 
 import jax
 import jax.numpy as jnp
@@ -7,14 +7,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.common.sharding import default_rules, resolve_spec
-from repro.launch import hlo_analysis as H
-
-
-@pytest.fixture(scope="module")
-def mesh():
-    # 1-device mesh: rule RESOLUTION logic is mesh-shape independent given
-    # divisibility, so we exercise fallbacks with a fake-shaped mesh object
-    return jax.make_mesh((1, 1), ("data", "model"))
 
 
 class FakeMesh:
@@ -67,98 +59,23 @@ class TestResolveSpec:
         assert rules["opt_embed"] is not None
 
 
-HLO_SAMPLE = """
-HloModule test
-
-%body (p: (s32[], f32[8,128])) -> (s32[], f32[8,128]) {
-  %p = (s32[], f32[8,128]) parameter(0)
-  %w = f32[128,128]{1,0} parameter(1)
-  %x = f32[8,128]{1,0} get-tuple-element(%p), index=1
-  %dot.1 = f32[8,128]{1,0} dot(%x, %w), lhs_contracting_dims={1}, rhs_contracting_dims={0}
-  %ar = f32[8,128]{1,0} all-reduce(%dot.1), replica_groups={}
-  ROOT %t = (s32[], f32[8,128]) tuple(%ar, %ar)
-}
-
-ENTRY %main (a: f32[8,128]) -> f32[8,128] {
-  %a = f32[8,128]{1,0} parameter(0)
-  %ag = f32[128,256]{1,0} all-gather(%a), dimensions={1}
-  %while.1 = (s32[], f32[8,128]) while(%a), condition=%cond, body=%body, backend_config={"known_trip_count":{"n":"10"}}
-  ROOT %out = f32[8,128]{1,0} get-tuple-element(%while.1), index=1
-}
-"""
-
-
-class TestHloAnalyzer:
-    def test_trip_count_weighting(self):
-        st = H.analyze(HLO_SAMPLE)
-        # dot: 2*8*128*128 flops, 10 trips
-        assert st.flops == pytest.approx(10 * 2 * 8 * 128 * 128)
-        # all-reduce in body: 2 * 8*128*4 bytes * 10 trips; all-gather once
-        ar = 10 * 2 * 8 * 128 * 4
-        ag = 128 * 256 * 4
-        assert st.coll_bytes["all-reduce"] == pytest.approx(ar)
-        assert st.coll_bytes["all-gather"] == pytest.approx(ag)
-
-    def test_kernel_scope_excluded_from_bytes(self):
-        txt = HLO_SAMPLE.replace(
-            "rhs_contracting_dims={0}",
-            'rhs_contracting_dims={0}, metadata={op_name="jit(f)/fusedkernel_flash_attention/dot"}',
-        )
-        st0 = H.analyze(HLO_SAMPLE)
-        st1 = H.analyze(txt)
-        assert st1.hbm_bytes < st0.hbm_bytes
-        assert st1.flops == st0.flops  # flops still counted
-
-
-@pytest.mark.slow
-def test_small_mesh_dryrun_lowering(mesh):
-    """End-to-end lowering of a reduced arch on a real (1,1) mesh: the same
-    build path the production dry-run uses."""
-    from repro.common.config import InputShape
-    from repro.launch.workload import build_steps
-    from repro.configs import get_config
-
-    cfg = get_config("yi-34b").reduced()
-    shape = InputShape("tiny_train", 32, 4, "train")
-    built = build_steps(cfg, shape, mesh=mesh)
-    with mesh:
-        lowered = jax.jit(built["step"], in_shardings=built["arg_shardings"],
-                          out_shardings=built["out_shardings"]).lower(*built["arg_specs"])
-        compiled = lowered.compile()
-    assert compiled.cost_analysis() is not None
-    stats = H.analyze(compiled.as_text())
-    assert stats.flops > 0
-
-
-def test_dryrun_prefill_attention_is_blocked_and_scoped(mesh):
-    """Off TPU the dry-run's prefill keeps blocked attention: its working set
+@pytest.mark.parametrize("arch", ["qwen2.5-7b", "minicpm-2b"])
+def test_host_prefill_attention_is_blocked_and_scoped(arch):
+    """Off TPU the model's prefill keeps blocked attention: its working set
     stays far below one layer's (B, H, S, S) float32 scores, and it carries
-    the ``fusedkernel_flash_attention`` scope that the roofline reads."""
-    from repro.common.config import InputShape
+    the ``fusedkernel_flash_attention`` scope that trace readers charge to
+    attention."""
     from repro.configs import get_config
-    from repro.launch.workload import build_steps
+    from repro.models.model_zoo import Runtime, build_model
 
-    cfg = get_config("qwen2.5-7b").reduced()
+    cfg = get_config(arch).reduced()
+    model = build_model(cfg)
     B, S = 2, 4096
-    built = build_steps(cfg, InputShape("tiny_prefill", S, B, "prefill"), mesh=mesh)
-    with mesh:
-        compiled = jax.jit(built["step"], in_shardings=built["arg_shardings"],
-                           out_shardings=built["out_shardings"]).lower(
-                               *built["arg_specs"]).compile()
+    batch = {"tokens": jax.ShapeDtypeStruct((B, S), jnp.int32),
+             "attn_valid": jax.ShapeDtypeStruct((B, S), jnp.bool_)}
+    prefill = jax.jit(lambda p, b: model.prefill(p, b, Runtime.local(),
+                                                 logits_mode="none"))
+    compiled = prefill.lower(model.param_shapes(), batch).compile()
     assert 'fusedkernel_flash_attention' in compiled.as_text()
     scores = B * cfg.n_heads * S * S * 4
     assert compiled.memory_analysis().temp_size_in_bytes < scores / 4
-
-
-def test_tpu_shardable_cfg_padding():
-    from repro.launch.workload import tpu_shardable_cfg
-    from repro.configs import get_config
-
-    yi = tpu_shardable_cfg(get_config("yi-34b"), 16)
-    assert yi.n_heads == 64 and yi.n_kv_heads == 8 and yi.head_dim == 128
-    wh = tpu_shardable_cfg(get_config("whisper-large-v3"), 16)
-    assert wh.n_heads == 32 and wh.n_kv_heads == 32
-    mb = tpu_shardable_cfg(get_config("mamba2-130m"), 16)
-    assert mb.ssm_n_heads == 32
-    ok = tpu_shardable_cfg(get_config("kimi-k2-1t-a32b"), 16)
-    assert ok.n_heads == 64 and ok.n_kv_heads == 8  # unchanged

@@ -90,16 +90,6 @@ def test_prod_head_kernel_compiles_at_served_width(one_chip, block_b):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_decode_attention_kernel_compiles(one_chip):
-    from repro.kernels.decode_attention import decode_attention_pallas
-
-    H, KV, hd, Sc = 28, 4, 128, SP + MAX_NEW
-    compiled = jax.jit(decode_attention_pallas).lower(
-        one_chip((B, H, hd)), one_chip((B, Sc, KV, hd)),
-        one_chip((B, Sc, KV, hd)), one_chip((B,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 def test_flash_attention_kernel_compiles(one_chip):
     from repro.kernels.flash_attention import flash_attention_pallas
 
